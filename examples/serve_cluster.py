@@ -29,13 +29,13 @@ import numpy as np
 
 from repro.configs import get_smoke_config
 from repro.core import make_policy
-from repro.launch.mesh import make_cpu_mesh
+from repro.launch.mesh import make_local_mesh
 from repro.models import init_params, split_params
 from repro.serving import EngineConfig, ServeRequest, ServingEngine
 
 cfg = get_smoke_config("granite-8b")
 params, _ = split_params(init_params(cfg, jax.random.PRNGKey(0)))
-mesh = make_cpu_mesh()
+mesh = make_local_mesh()
 
 
 def make_requests():

@@ -9,7 +9,7 @@ import jax
 
 from repro.configs.base import ModelConfig
 from repro.data import token_batches
-from repro.launch.mesh import make_cpu_mesh
+from repro.launch.mesh import make_local_mesh
 from repro.models import init_params, split_params
 from repro.training import AdamWConfig, load_checkpoint, train
 
@@ -21,7 +21,7 @@ cfg = ModelConfig(
 print(f"model: {cfg.n_params()/1e6:.1f}M params")
 
 params, _ = split_params(init_params(cfg, jax.random.PRNGKey(0)))
-mesh = make_cpu_mesh()
+mesh = make_local_mesh()
 
 STEPS = 200
 batches = token_batches(vocab_size=cfg.vocab_size, batch=8, seq_len=64,

@@ -22,7 +22,7 @@ compute the identical best-improving pair per iteration (see
   CPU path.
 * ``method="pallas"`` — a Pallas kernel on a (N/TILE_I, N/TILE_J) grid
   with the running per-row argmin carried in the revisited output block,
-  so no pairwise tensor is ever materialized; interpret mode off-TPU.
+  so no pairwise tensor is ever materialized; interpreted off-TPU.
 
 Candidate pruning
 -----------------
@@ -230,15 +230,16 @@ def _refine(loads, assign, cands, valid, *, swap_iters, method, tile,
                                              "prune_k", "interpret"))
 def bfio_assign(base, caps, cands, valid, n_admit, swap_iters: int = 8,
                 *, method: str = "xla", tile: int = 128,
-                prune_k: int | None = None, interpret: bool = True):
+                prune_k: int | None = None, interpret: bool | None = None):
     """Jitted BF-IO assignment (greedy + fixed-budget swap refinement).
 
     ``method`` selects the swap-search backend ("xla" | "pallas" |
     "dense"), ``tile`` the block size, ``prune_k`` the optional top-K
-    candidate pruning, ``interpret`` the Pallas interpret mode (keep True
-    off-TPU).  All backends return identical assignments for the same
-    inputs; ``prune_k`` trades a measured sliver of objective for a K^2/N^2
-    reduction in pair-search cost.
+    candidate pruning, ``interpret`` the Pallas interpret mode (``None``:
+    derived from the platform, native on TPU).  All backends return
+    identical assignments for the same inputs; ``prune_k`` trades a
+    measured sliver of objective for a K^2/N^2 reduction in pair-search
+    cost.
     """
     base = jnp.asarray(base, dtype=jnp.float32)
     cands = jnp.asarray(cands, dtype=jnp.float32)

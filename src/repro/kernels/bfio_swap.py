@@ -23,10 +23,8 @@ Three interchangeable backends with identical semantics:
 * ``swap_best_pallas`` — Pallas kernel, grid (N/TILE_I, N/TILE_J), the
   running argmin carried in the revisited output block across the inner
   j-grid dimension.  Interpret mode on CPU (correctness), native on TPU.
-  For TPU the W axis can be zero-padded to the 128-lane boundary
-  (``pad_lanes``): padded lanes contribute max(-inf, 0, 0) = 0 to the
-  windowed sum, so results are unchanged for the non-negative loads of
-  this problem.
+  The window W is looped over inside the kernel, so it needs no lane
+  alignment.
 * ``swap_best_xla`` — pure-XLA fallback tiled over i only (``lax.map``
   over row blocks, full j extent per block); the production CPU path.
 * ``swap_best_dense`` lives in ``ref.py`` as the O(N^2 W) oracle.
@@ -43,6 +41,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .platform import resolve_interpret
 
 __all__ = ["swap_prep", "swap_best_pallas", "swap_best_xla", "swap_best"]
 
@@ -93,9 +94,19 @@ def _pair_vals(ci, li, gai, admi, cj, lj, gaj, admj, vtop, ttop):
     return jnp.where(feas, val, jnp.inf)                    # (I, J)
 
 
-def _swap_kernel(ci_ref, li_ref, gai_ref, admi_ref,
-                 cj_ref, lj_ref, gaj_ref, admj_ref,
-                 vtop_ref, ttop_ref, val_ref, arg_ref, *, tile_j: int):
+def _swap_kernel(vtop_ref, ttop_ref, ci_ref, li_ref, gai_ref, admi_ref,
+                 cj_ref, lj_ref, gaj_ref, admj_ref, val_ref, arg_ref, *,
+                 tile_j: int, width: int):
+    """One (TILE_I, TILE_J) block of the swap search, TPU layout.
+
+    The i side arrives row-major ((TILE_I, W) and (TILE_I, 1) columns),
+    the j side transposed ((W, TILE_J) and (1, TILE_J) rows), and the
+    top-3 table as SMEM scalars, so every window position ``w`` is one
+    2-D (TILE_I, TILE_J) plane: i on sublanes, j on lanes.  No 3-D
+    (I, J, W) tensor exists — its W-minor layout would pad every (J, W)
+    slab to full (8, 128) tiles.  The windowed sum folds the planes in
+    order w = 0, 1, ...; XLA's CPU reduction over W (<= 16) adds in the
+    same order, so interpret mode stays bit-identical to the oracles."""
     jblk = pl.program_id(1)
 
     @pl.when(jblk == 0)
@@ -103,12 +114,29 @@ def _swap_kernel(ci_ref, li_ref, gai_ref, admi_ref,
         val_ref[...] = jnp.full_like(val_ref[...], jnp.inf)
         arg_ref[...] = jnp.zeros_like(arg_ref[...])
 
-    val = _pair_vals(
-        ci_ref[...], li_ref[...], gai_ref[...][:, 0], admi_ref[...][:, 0] > 0,
-        cj_ref[...], lj_ref[...], gaj_ref[...][:, 0], admj_ref[...][:, 0] > 0,
-        vtop_ref[...], ttop_ref[...])
-    row_min = val.min(axis=1)
-    row_arg = val.argmin(axis=1).astype(jnp.int32) + jblk * tile_j
+    shape = (ci_ref.shape[0], cj_ref.shape[1])
+    gi = jnp.broadcast_to(gai_ref[...], shape)
+    gj = jnp.broadcast_to(gaj_ref[...], shape)
+    acc = None
+    for w in range(width):
+        diff = cj_ref[w:w + 1, :] - ci_ref[:, w:w + 1]   # (I, J)
+        la = li_ref[:, w:w + 1] + diff                    # g_i row after swap
+        lb = lj_ref[w:w + 1, :] - diff                    # g_j row after swap
+        t1, t2 = ttop_ref[0, w], ttop_ref[1, w]
+        e1 = (t1 != gi) & (t1 != gj)
+        e2 = (t2 != gi) & (t2 != gj)
+        ex = jnp.where(e1, vtop_ref[0, w],
+                       jnp.where(e2, vtop_ref[1, w], vtop_ref[2, w]))
+        m = jnp.maximum(ex, jnp.maximum(la, lb))
+        acc = m if acc is None else acc + m
+    feas = ((jnp.broadcast_to(admi_ref[...], shape) > 0)
+            & (jnp.broadcast_to(admj_ref[...], shape) > 0) & (gi != gj))
+    val = jnp.where(feas, acc, jnp.inf)
+    row_min = val.min(axis=1, keepdims=True)                 # (I, 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    # first minimizer of each row (argmin's tie-breaking)
+    row_arg = jnp.min(jnp.where(val == row_min, col, tile_j), axis=1,
+                      keepdims=True) + jblk * tile_j
     prev_v, prev_a = val_ref[...], arg_ref[...]
     better = row_min < prev_v                    # strict: keep first minimizer
     val_ref[...] = jnp.where(better, row_min, prev_v)
@@ -121,12 +149,15 @@ def _pad_rows(x, n, fill=0):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("tile_i", "tile_j", "interpret",
-                                    "pad_lanes"))
-def swap_best_pallas(loads, cands, assign, valid, *, tile_i: int = 64,
-                     tile_j: int = 64, interpret: bool = True,
-                     pad_lanes: bool = False):
-    """Pallas tiled swap search.  Returns (best_val (N,), best_j (N,))."""
+                   static_argnames=("tile_i", "tile_j", "interpret"))
+def swap_best_pallas(loads, cands, assign, valid, *, tile_i: int = 128,
+                     tile_j: int = 128, interpret: bool | None = None):
+    """Pallas tiled swap search.  Returns (best_val (N,), best_j (N,)).
+
+    On the TPU ``tile_j`` must be a multiple of 128 (it is the lane axis
+    of every block) unless one tile covers all N candidates; ``tile_i``
+    a multiple of 8.  ``interpret=None`` derives the mode from the
+    platform (:func:`~repro.kernels.platform.resolve_interpret`)."""
     lo, ga, adm, vtop, ttop = swap_prep(loads, cands, assign, valid)
     cands = jnp.asarray(cands, jnp.float32)
     N, W = cands.shape
@@ -134,43 +165,37 @@ def swap_best_pallas(loads, cands, assign, valid, *, tile_i: int = 64,
     np_i = pl.cdiv(N, tile_i) * tile_i
     np_j = pl.cdiv(N, tile_j) * tile_j
     npad = max(np_i, np_j)
-    if pad_lanes and W % 128:                    # TPU lane alignment
-        wpad = (-W) % 128
-        cands = jnp.pad(cands, ((0, 0), (0, wpad)))
-        lo = jnp.pad(lo, ((0, 0), (0, wpad)))
-        vtop = jnp.pad(vtop, ((0, 0), (0, wpad)), constant_values=-jnp.inf)
-        ttop = jnp.pad(ttop, ((0, 0), (0, wpad)), constant_values=-1)
-        W += wpad
     cands = _pad_rows(cands, npad)
     lo = _pad_rows(lo, npad)
     ga2 = _pad_rows(ga, npad)[:, None]
     adm2 = _pad_rows(adm.astype(jnp.int32), npad)[:, None]
 
     grid = (npad // tile_i, npad // tile_j)
-    ispec = lambda bs: pl.BlockSpec(bs, lambda i, j: (i, 0))  # noqa: E731
-    jspec = lambda bs: pl.BlockSpec(bs, lambda i, j: (j, 0))  # noqa: E731
+    ispec = lambda bs: pl.BlockSpec(bs, lambda i, j, *_: (i, 0))  # noqa: E731
+    jspec = lambda bs: pl.BlockSpec(bs, lambda i, j, *_: (0, j))  # noqa: E731
+    ospec = pl.BlockSpec((tile_i, 1), lambda i, j, *_: (i, 0))
     vals, args = pl.pallas_call(
-        functools.partial(_swap_kernel, tile_j=tile_j),
-        grid=grid,
-        in_specs=[
-            ispec((tile_i, W)), ispec((tile_i, W)),
-            ispec((tile_i, 1)), ispec((tile_i, 1)),
-            jspec((tile_j, W)), jspec((tile_j, W)),
-            jspec((tile_j, 1)), jspec((tile_j, 1)),
-            pl.BlockSpec((3, W), lambda i, j: (0, 0)),
-            pl.BlockSpec((2, W), lambda i, j: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile_i,), lambda i, j: (i,)),
-            pl.BlockSpec((tile_i,), lambda i, j: (i,)),
-        ],
+        functools.partial(_swap_kernel, tile_j=tile_j, width=W),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=grid,
+            in_specs=[
+                ispec((tile_i, W)), ispec((tile_i, W)),
+                ispec((tile_i, 1)), ispec((tile_i, 1)),
+                jspec((W, tile_j)), jspec((W, tile_j)),
+                jspec((1, tile_j)), jspec((1, tile_j)),
+            ],
+            out_specs=[ospec, ospec],
+        ),
         out_shape=[
-            jax.ShapeDtypeStruct((npad,), jnp.float32),
-            jax.ShapeDtypeStruct((npad,), jnp.int32),
+            jax.ShapeDtypeStruct((npad, 1), jnp.float32),
+            jax.ShapeDtypeStruct((npad, 1), jnp.int32),
         ],
-        interpret=interpret,
-    )(cands, lo, ga2, adm2, cands, lo, ga2, adm2, vtop, ttop)
-    return vals[:N], args[:N]
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=resolve_interpret(interpret),
+    )(vtop, ttop, cands, lo, ga2, adm2, cands.T, lo.T, ga2.T, adm2.T)
+    return vals[:N, 0], args[:N, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("tile_i",))

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .platform import resolve_interpret
+
 __all__ = ["decode_attention_pallas"]
 
 _NEG = -1e30
@@ -73,11 +75,12 @@ def _kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref,
 @functools.partial(jax.jit,
                    static_argnames=("blk_l", "interpret"))
 def decode_attention_pallas(q, k_cache, v_cache, lengths, *,
-                            blk_l: int = 512, interpret: bool = True):
+                            blk_l: int = 512,
+                            interpret: bool | None = None):
     """q: (B, Hq, hd); k_cache/v_cache: (B, L, Hkv, hd); lengths: (B,).
 
-    Returns (B, Hq, hd).  ``interpret=True`` executes the kernel body in
-    Python on CPU (validation mode); on TPU pass interpret=False.
+    Returns (B, Hq, hd).  ``interpret=None`` derives the mode from the
+    platform (:func:`~repro.kernels.platform.resolve_interpret`).
     """
     B, Hq, hd = q.shape
     L, Hkv = k_cache.shape[1], k_cache.shape[2]
@@ -113,6 +116,6 @@ def decode_attention_pallas(q, k_cache, v_cache, lengths, *,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lengths.astype(jnp.int32), qg, k_cache, v_cache)
     return out.reshape(B, Hq, hd)

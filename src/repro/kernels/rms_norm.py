@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .platform import resolve_interpret
+
 __all__ = ["rms_norm_pallas"]
 
 
@@ -26,7 +28,7 @@ def _kernel(x_ref, s_ref, o_ref, *, eps: float):
 
 @functools.partial(jax.jit, static_argnames=("blk_r", "interpret"))
 def rms_norm_pallas(x, scale, *, eps: float = 1e-5, blk_r: int = 256,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """x: (..., d); scale: (d,).  Returns same shape/dtype as x."""
     orig_shape = x.shape
     d = x.shape[-1]
@@ -46,7 +48,7 @@ def rms_norm_pallas(x, scale, *, eps: float = 1e-5, blk_r: int = 256,
         ],
         out_specs=pl.BlockSpec((blk_r, d), lambda r: (r, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x2, scale)
     if pad:
         out = out[:R]

@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .platform import resolve_interpret
+
 __all__ = ["ssm_chunk_scan_pallas"]
 
 
@@ -71,7 +73,7 @@ def _kernel(q_ref, k_ref, v_ref, a_ref, g_ref, y_ref, s_out_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssm_chunk_scan_pallas(q, k, v, log_decay, gate, *, chunk: int = 128,
-                          interpret: bool = True):
+                          interpret: bool | None = None):
     """q, k: (B, S, H, dk); v: (B, S, H, dv); log_decay/gate: (B, S, H).
 
     Returns (y (B, S, H, dv), final_state (B, H, dk, dv)).
@@ -102,6 +104,6 @@ def ssm_chunk_scan_pallas(q, k, v, log_decay, gate, *, chunk: int = 128,
             jax.ShapeDtypeStruct((B, H, dk, dv), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v, log_decay, gate)
     return y, s_out

@@ -9,7 +9,7 @@ from .mesh import (  # noqa: F401
     batch_axes_for,
     batch_shardings,
     cache_shardings,
-    make_cpu_mesh,
+    make_local_mesh,
     make_production_mesh,
     param_shardings,
 )

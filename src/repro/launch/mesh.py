@@ -1,8 +1,9 @@
 """Production meshes and sharding rules.
 
-``make_production_mesh`` builds the 16x16 single-pod (256 chips) or
-2x16x16 multi-pod (512 chips) mesh — as a FUNCTION so importing this module
-never touches jax device state.
+``make_local_mesh`` builds the one-device mesh everything that runs uses;
+``make_production_mesh`` the 16x16 single-pod (256 chips) or 2x16x16
+multi-pod (512 chips) mesh that ``dryrun.py`` compiles for.  Both are
+functions, so importing this module never touches jax device state.
 
 ``ShardingRules`` maps the *logical* parameter axes emitted by the model
 init (repro.models.layers.Param) to physical mesh axes, divisibility-aware
@@ -25,14 +26,14 @@ from typing import Optional
 
 import jax
 import numpy as np
-from jax.sharding import NamedSharding
+from jax.sharding import AxisType, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ModelConfig
 
 __all__ = [
     "make_production_mesh",
-    "make_cpu_mesh",
+    "make_local_mesh",
     "batch_axes_for",
     "ShardingRules",
     "param_shardings",
@@ -42,25 +43,32 @@ __all__ = [
 ]
 
 
-def _make_mesh(shape, axes):
-    # jax 0.4.x has no jax.sharding.AxisType; Auto is the default there
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+def _make_mesh(shape, axes, devices=None):
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
-    """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
+    """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips).
+
+    Compile-only: ``launch/dryrun.py`` lowers onto it over fake host
+    devices.  Nothing that runs builds it."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return _make_mesh(shape, axes)
 
 
-def make_cpu_mesh():
-    """Trivial (1, 1) mesh for CPU tests — same axis names."""
-    return _make_mesh((1, 1), ("data", "model"))
+def make_local_mesh():
+    """(1, 1) ("data", "model") mesh on the first device present: one
+    chip on a TPU host, the CPU under ``JAX_PLATFORMS=cpu``.
+
+    The serving engine and fleet keep every worker and replica on one
+    device (ROADMAP R4), so this is the mesh the launchers, tests and
+    benchmarks run on.  ``Auto`` axes: with JAX's default ``Explicit``
+    axes, ``shard_map`` outputs carry a sharding that the MoE layer's
+    next gather rejects."""
+    return _make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
 
 
 def batch_axes_for(mesh, global_batch: Optional[int] = None) -> tuple:

@@ -77,7 +77,30 @@ from ..fleet.workloads import SCENARIOS as FLEET_SCENARIOS
 from ..models import init_params, split_params
 from ..obs import SpanRecorder, read_trace, write_trace
 from ..serving import EngineConfig, ServeRequest, ServingEngine
-from .mesh import make_cpu_mesh, make_production_mesh
+from .compile_cache import enable_compile_cache
+from .mesh import make_local_mesh
+
+
+def select_model(arch: str, smoke: bool):
+    """(config, mesh) the launcher serves.  ``--smoke`` alone picks the
+    reduced config: the backend never does, so on the CPU the full
+    config is what runs (or fails to fit) unless ``--smoke`` is given.
+    The mesh is one present device (:func:`~repro.launch.mesh.
+    make_local_mesh`): engine workers and fleet replicas all live on it
+    (ROADMAP R4)."""
+    cfg = get_smoke_config(arch) if smoke else get_config(arch)
+    return cfg, make_local_mesh()
+
+
+def init_serving_params(cfg, seed: int = 0):
+    """Random weights from ``seed`` as a value tree (the logical axes are
+    dropped: nothing on one device shards).  One jitted program, so each
+    float32 draw fuses into its cast to ``cfg.dtype`` instead of landing
+    in device memory first (minitron-4b's float32 embedding draw alone
+    would be 2.9 GiB)."""
+    def init(key):
+        return split_params(init_params(cfg, key))[0]
+    return jax.jit(init)(jax.random.PRNGKey(seed))
 
 
 def parse_replica_classes(spec: str, engine_cfg):
@@ -145,8 +168,8 @@ def serve_fleet(args, cfg, params, engine_cfg, mesh) -> None:
     print(f"[fleet] {cfg.name} R={stats['n_replicas']} "
           f"router={stats['router']} "
           f"scenario={args.scenario or 'synthetic'}: "
-          f"{stats['tokens']} tokens in {stats['steps']} steps, "
-          f"{stats['throughput_tok_s']:.1f} tok/s, "
+          f"{stats['tokens']} tokens in {stats['steps']} steps; "
+          f"modelled clock: {stats['throughput_tok_s']:.1f} tok/s, "
           f"E={stats['energy_j']:.1f} J "
           f"({stats['idle_j']:.1f} J barrier idle), "
           f"{stats['energy_per_token']:.3f} J/tok, "
@@ -191,7 +214,9 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--policy", default="bfio_h8")
-    ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced same-family config (the only way to get "
+                         "one: the backend never swaps it in)")
     ap.add_argument("--workers", type=int, default=4)
     ap.add_argument("--slots", type=int, default=8)
     ap.add_argument("--requests", type=int, default=32)
@@ -281,14 +306,9 @@ def main() -> None:
                          "ledger's idle-energy attribution")
     args = ap.parse_args()
 
-    if args.smoke or jax.default_backend() == "cpu":
-        cfg = get_smoke_config(args.arch)
-        mesh = make_cpu_mesh()
-    else:  # pragma: no cover - real hardware path
-        cfg = get_config(args.arch)
-        mesh = make_production_mesh()
-
-    params, _ = split_params(init_params(cfg, jax.random.PRNGKey(0)))
+    enable_compile_cache()
+    cfg, mesh = select_model(args.arch, args.smoke)
+    params = init_serving_params(cfg, args.seed)
     engine_cfg = EngineConfig(
         n_workers=args.workers, slots_per_worker=args.slots,
         max_seq_len=256, cache_backend=args.cache_backend,
@@ -315,8 +335,8 @@ def main() -> None:
             max_new_tokens=args.max_new))
     stats = eng.run()
     print(f"[serve] {cfg.name} policy={stats['policy']}: "
-          f"{stats['tokens']} tokens in {stats['steps']} steps, "
-          f"{stats['throughput_tok_s']:.1f} tok/s, "
+          f"{stats['tokens']} tokens in {stats['steps']} steps; "
+          f"modelled clock: {stats['throughput_tok_s']:.1f} tok/s, "
           f"E={stats['energy_j']:.1f} J, "
           f"avg imbalance {stats['avg_imbalance']:.1f}")
     if args.cache_backend == "paged":

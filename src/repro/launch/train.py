@@ -1,7 +1,8 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> ...``
 
-On real hardware this drives the production mesh; on CPU it runs the smoke
-variant end-to-end (the same code path the dry-run lowers)."""
+Trains on one present device (:func:`~repro.launch.mesh.make_local_mesh`);
+``--smoke`` alone picks the reduced config, whatever the backend.  The
+production meshes are compile-only (``launch/dryrun.py``)."""
 from __future__ import annotations
 
 import argparse
@@ -13,7 +14,8 @@ from ..configs import get_config, get_smoke_config
 from ..data import token_batches
 from ..models import init_params, split_params
 from ..training import AdamWConfig, train
-from .mesh import make_cpu_mesh, make_production_mesh
+from .compile_cache import enable_compile_cache
+from .mesh import make_local_mesh
 
 
 def main() -> None:
@@ -26,15 +28,11 @@ def main() -> None:
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--ckpt-dir", default=None)
-    ap.add_argument("--multi-pod", action="store_true")
     args = ap.parse_args()
 
-    if args.smoke or jax.default_backend() == "cpu":
-        cfg = get_smoke_config(args.arch)
-        mesh = make_cpu_mesh()
-    else:  # pragma: no cover - real hardware path
-        cfg = get_config(args.arch)
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
+    enable_compile_cache()
+    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    mesh = make_local_mesh()
 
     print(f"[train] {cfg.name}: {cfg.n_params()/1e6:.1f}M params, "
           f"mesh {dict(mesh.shape)}")

@@ -17,8 +17,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ._compat import shard_map
-
 __all__ = ["causal_attention", "chunk_attention", "cross_attention",
            "decode_attention"]
 
@@ -367,7 +365,7 @@ def decode_attention_lsharded(q, k_cache, v_cache, lengths, *, mesh,
         out = acc_tot / jnp.maximum(l_tot, 1e-30)[..., None]
         return out.reshape(b, hq, hd).astype(q.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(b_spec, None, None),
                   P(b_spec, model_axis, None, None),

@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ._compat import shard_map
 from .layers import linear
 
 __all__ = ["router_topk", "aux_load_balance_loss", "moe_ffn"]
@@ -136,7 +135,7 @@ def moe_ffn(
 
     fn = functools.partial(_local_moe, E=n_experts, k=k, capacity=capacity,
                            ep_mode=ep_mode, model_axis=model_axis)
-    out = shard_map(
+    out = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(bspec, ispec, ispec, w13_spec, w13_spec, w2_spec),
         out_specs=bspec,

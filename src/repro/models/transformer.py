@@ -189,13 +189,15 @@ def _block_init(key, cfg: ModelConfig, kind: str, dtype) -> dict:
 
 
 def _stack_init(key, cfg: ModelConfig, kind: str, n: int, dtype) -> dict:
-    """n stacked blocks (leading scan axis on every leaf)."""
+    """n stacked blocks (leading scan axis on every leaf).
+
+    One vmapped draw per weight over the layer keys: the same values as
+    n per-layer draws stacked, without n separate blocks plus their
+    stacked copy in memory, and one block's program to compile, not n."""
     keys = jax.random.split(key, n)
-    blocks = [_block_init(k, cfg, kind, dtype) for k in keys]
-    return jax.tree.map(
-        lambda *xs: Param(jnp.stack([x.value for x in xs]),
-                          ("layers",) + xs[0].axes),
-        *blocks, is_leaf=lambda x: isinstance(x, Param))
+    stacked = jax.vmap(lambda k: _block_init(k, cfg, kind, dtype))(keys)
+    return jax.tree.map(lambda p: Param(p.value, ("layers",) + p.axes),
+                        stacked, is_leaf=lambda x: isinstance(x, Param))
 
 
 def layer_pattern(cfg: ModelConfig) -> list[tuple[str, str, int]]:

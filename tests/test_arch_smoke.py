@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config, list_archs
+from repro.launch.mesh import make_local_mesh
 from repro.models import (
     decode_fn,
     init_params,
@@ -24,7 +25,7 @@ ARCHS = list_archs()
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_local_mesh()
 
 
 def _batch(cfg, B=2, S=16, seed=0):

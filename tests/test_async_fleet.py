@@ -36,6 +36,7 @@ from repro.fleet import (
 )
 from repro.fleet.async_server import ACTIVE
 from repro.fleet.telemetry import ACCEPTED_VERSIONS, SCHEMA_VERSION
+from repro.launch.mesh import make_local_mesh
 from repro.models import init_params, split_params
 from repro.serving import EngineConfig, ServeRequest
 
@@ -59,7 +60,7 @@ def _setup():
     if not _SETUP:
         params, _ = split_params(init_params(CFG, jax.random.PRNGKey(0)))
         _SETUP["params"] = params
-        _SETUP["mesh"] = jax.make_mesh((1, 1), ("data", "model"))
+        _SETUP["mesh"] = make_local_mesh()
     return _SETUP["params"], _SETUP["mesh"]
 
 

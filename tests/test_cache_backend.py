@@ -29,6 +29,7 @@ import pytest
 
 from repro.configs.base import ModelConfig
 from repro.core import make_policy
+from repro.launch.mesh import make_local_mesh
 from repro.models import (
     init_cache,
     init_params,
@@ -62,14 +63,14 @@ STAT_KEYS = ("steps", "tokens", "energy_j", "avg_imbalance", "time_s")
 @pytest.fixture(scope="module")
 def setup():
     params, _ = split_params(init_params(CFG, jax.random.PRNGKey(0)))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh()
     return params, mesh
 
 
 @pytest.fixture(scope="module")
 def moe_setup():
     params, _ = split_params(init_params(MOE_CFG, jax.random.PRNGKey(1)))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh()
     return params, mesh
 
 
@@ -185,7 +186,7 @@ class TestResidentKV:
 
     def test_unsupported_family_rejected(self):
         # params never touched: the backend rejects the family up front
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_local_mesh()
         assert not supports_paged_stack(SSM_CFG)
         with pytest.raises(ValueError, match="attention-family"):
             ServingEngine(SSM_CFG, None,
@@ -345,7 +346,7 @@ class TestChunkedPrefill:
             "policy never observed in-flight chunk progress"
 
     def test_chunked_rejected_for_non_attn_families(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_local_mesh()
         with pytest.raises(ValueError, match="chunked prefill"):
             ServingEngine(SSM_CFG, None,
                           EngineConfig(prefill_chunk=16),

@@ -15,6 +15,7 @@ from repro.configs.base import ModelConfig
 from repro.core import make_policy
 from repro.core.policies import Policy
 from repro.core.workload import constant_drift, fractional_drift, unit_drift
+from repro.launch.mesh import make_local_mesh
 from repro.models import init_params, split_params
 from repro.serving import (
     EngineConfig,
@@ -34,7 +35,7 @@ STAT_KEYS = ("steps", "tokens", "energy_j", "avg_imbalance", "time_s")
 @pytest.fixture(scope="module")
 def setup():
     params, _ = split_params(init_params(CFG, jax.random.PRNGKey(0)))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh()
     return params, mesh
 
 

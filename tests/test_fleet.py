@@ -32,6 +32,7 @@ from repro.fleet import (
     make_scenario,
     validate_scenario,
 )
+from repro.launch.mesh import make_local_mesh
 from repro.models import init_params, split_params
 from repro.serving import EngineConfig, ServeRequest, ServingEngine
 
@@ -44,7 +45,7 @@ ROUTERS = ("round_robin", "least_loaded", "pod2", "bfio")
 @pytest.fixture(scope="module")
 def setup():
     params, _ = split_params(init_params(CFG, jax.random.PRNGKey(0)))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh()
     return params, mesh
 
 

@@ -34,6 +34,7 @@ from repro.fleet import (
     SLOSpec,
     TargetUtilizationAutoscaler,
 )
+from repro.launch.mesh import make_local_mesh
 from repro.models import init_params, split_params
 from repro.obs import (
     IDLE_CAUSES,
@@ -59,7 +60,7 @@ TIMING = dict(step_overhead=1e-3, t_token=2e-4)
 @pytest.fixture(scope="module")
 def setup():
     params, _ = split_params(init_params(CFG, jax.random.PRNGKey(0)))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh()
     return params, mesh
 
 

@@ -23,6 +23,7 @@ import pytest
 
 from repro.configs.base import ModelConfig
 from repro.core import make_policy
+from repro.launch.mesh import make_local_mesh
 from repro.models import init_params, split_params
 from repro.serving import (
     BlockAllocator,
@@ -68,7 +69,7 @@ STAT_KEYS = ("steps", "tokens", "energy_j", "avg_imbalance", "time_s")
 @pytest.fixture(scope="module")
 def setup():
     params, _ = split_params(init_params(CFG, jax.random.PRNGKey(0)))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh()
     return params, mesh
 
 

@@ -12,6 +12,7 @@ import pytest
 
 from repro.configs.base import ModelConfig
 from repro.core import make_policy
+from repro.launch.mesh import make_local_mesh
 from repro.models import init_params, split_params
 from repro.serving import EngineConfig, ServeRequest, ServingEngine
 
@@ -23,7 +24,7 @@ CFG = ModelConfig(name="tiny", family="dense", n_layers=2, d_model=64,
 @pytest.fixture(scope="module")
 def setup():
     params, _ = split_params(init_params(CFG, jax.random.PRNGKey(0)))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh()
     return params, mesh
 
 

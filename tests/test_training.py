@@ -11,6 +11,7 @@ import pytest
 
 from repro.configs.base import ModelConfig
 from repro.data import token_batches
+from repro.launch.mesh import make_local_mesh
 from repro.models import init_params, loss_fn, split_params
 from repro.training import (
     AdamWConfig,
@@ -73,7 +74,7 @@ class TestOptimizer:
 class TestGradAccum:
     def test_accum_matches_full_batch(self):
         """grad_accum=2 must give the same update as the full batch."""
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_local_mesh()
         params, _ = split_params(init_params(CFG, jax.random.PRNGKey(0)))
         batch = next(token_batches(vocab_size=64, batch=4, seq_len=16,
                                    n_batches=1, seed=0))
@@ -119,7 +120,7 @@ class TestCheckpoint:
 class TestEndToEnd:
     def test_loss_decreases(self):
         """~60 steps on a memorizable stream: loss must drop clearly."""
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_local_mesh()
         params, _ = split_params(init_params(CFG, jax.random.PRNGKey(1)))
         batches = list(token_batches(vocab_size=64, batch=8, seq_len=32,
                                      n_batches=8, seed=1)) * 8
