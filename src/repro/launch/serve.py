@@ -76,7 +76,7 @@ from ..fleet import (
 from ..fleet.workloads import SCENARIOS as FLEET_SCENARIOS
 from ..models import init_params, split_params
 from ..obs import SpanRecorder, read_trace, write_trace
-from ..serving import EngineConfig, ServeRequest, ServingEngine
+from ..serving import EngineConfig, ServeRequest, ServingEngine, host_phases
 from .compile_cache import enable_compile_cache
 from .mesh import make_local_mesh
 
@@ -117,6 +117,16 @@ def parse_replica_classes(spec: str, engine_cfg):
         out.append((count, dataclasses.replace(
             engine_cfg, n_workers=g, slots_per_worker=b)))
     return out
+
+
+def format_host_phases(requests) -> str:
+    """One line: the host-clock phases of the finished requests
+    (:func:`~repro.serving.host_phases`), median and 90th percentile."""
+    parts = [f"{name} {np.percentile(d, 50) * 1e3:.1f} / "
+             f"{np.percentile(d, 90) * 1e3:.1f}"
+             for name, d in host_phases(requests).items() if d.size]
+    return ("[serve] host clock, p50 / p90 ms: "
+            + (", ".join(parts) or "no finished request"))
 
 
 def serve_fleet(args, cfg, params, engine_cfg, mesh) -> None:
@@ -327,18 +337,20 @@ def main() -> None:
                         make_policy(args.policy), mesh=mesh)
 
     rng = np.random.default_rng(args.seed)
-    for i in range(args.requests):
-        eng.submit(ServeRequest(
-            rid=i,
-            tokens=rng.integers(1, cfg.vocab_size,
-                                size=int(rng.integers(4, 64))),
-            max_new_tokens=args.max_new))
+    reqs = [ServeRequest(rid=i,
+                         tokens=rng.integers(1, cfg.vocab_size,
+                                             size=int(rng.integers(4, 64))),
+                         max_new_tokens=args.max_new)
+            for i in range(args.requests)]
+    for r in reqs:
+        eng.submit(r)
     stats = eng.run()
     print(f"[serve] {cfg.name} policy={stats['policy']}: "
           f"{stats['tokens']} tokens in {stats['steps']} steps; "
           f"modelled clock: {stats['throughput_tok_s']:.1f} tok/s, "
           f"E={stats['energy_j']:.1f} J, "
           f"avg imbalance {stats['avg_imbalance']:.1f}")
+    print(format_host_phases(reqs))
     if args.cache_backend == "paged":
         # what the contiguous slot layout would pin (every slot at
         # max_seq_len) — NOT the pool size, which --pool-blocks may have

@@ -1,33 +1,59 @@
-"""Per-request tracing and barrier straggler attribution.
+"""Per-request tracing, phase spans and barrier straggler attribution.
 
 The fleet's telemetry (:mod:`repro.fleet.telemetry`) records *aggregate*
 idle energy and imbalance per step.  This package is the microscope
-underneath those totals — two instruments threaded through
+underneath those totals — three instruments threaded through
 :class:`~repro.serving.engine.ServingEngine`,
 :class:`~repro.fleet.server.FleetServer`, and
-:class:`~repro.fleet.async_server.AsyncFleetServer`:
+:class:`~repro.fleet.async_server.AsyncFleetServer`.  Which clock each
+field is on:
+
+* the **modelled clock** (the engine's ``t_now``, ``step_overhead +
+  t_token * max load`` per step; the fleet's clock): recorder points'
+  ``t``, the trace's ``ts``, ``e2e_s`` / ``decode_s``, the request
+  fields ``t_submit`` / ``t_first_token`` / ``t_finish``, telemetry and
+  the ledger's joules.  Deterministic, never a speed;
+* the **host clock** (``time.perf_counter()``): the request fields
+  ``t_admit_host`` / ``t_first_chunk_host`` / ``t_first_token_host`` /
+  ``t_finish_host`` (split into phases by
+  :func:`repro.serving.host_phases`) and the ``host_s`` arg of every
+  point the engine emits;
+* the **device trace's clock** (a running ``jax.profiler``): the phase
+  spans of :mod:`repro.obs.spans`.
+
+**Phase spans** (:mod:`repro.obs.spans`, imported from there: it is
+the one module here that needs jax).  ``ServingEngine.step`` and its
+scheduler and cache backend open one ``TraceAnnotation`` per host phase
+(names in ``SPANS``), so a profile shows which phase held the device
+idle and how full each program's rows were.
 
 **Per-request spans** (:mod:`repro.obs.trace`).  Every request emits
-lifecycle point events on the deterministic sim clock; the exporter
-derives duration spans from them and writes Chrome trace-event /
-Perfetto JSON (``--trace-out`` on ``launch/serve.py``;
-:func:`read_trace` is the validating reader).  The span taxonomy:
+lifecycle point events on the deterministic sim clock (engine points
+also carry ``host_s``); the exporter derives duration spans from them
+and writes Chrome trace-event / Perfetto JSON (``--trace-out`` on
+``launch/serve.py``; :func:`read_trace` is the validating reader).  The
+span taxonomy:
 
 * ``queued`` — the request enters the fleet (at its arrival time) or a
   bare engine's wait queue;
 * ``routed`` — the fleet router assigns it to a replica;
 * ``admitted`` — the engine claims a slot (sync prefill or a chunked
-  prefill job);
+  prefill job); the first one stamps ``t_admit_host``;
 * ``prefill-chunk`` — one chunked-prefill advance (offset + token
-  count in args);
-* ``decode`` — the first token lands; decode begins;
+  count in args); the first one stamps ``t_first_chunk_host``;
+* ``decode`` — the first token lands; decode begins; stamps
+  ``t_first_token_host``;
 * ``preempted`` — swap-out or recompute-drop under memory pressure
   (mode in args);
 * ``resumed`` — a preempted victim re-enters a slot (swap-in restore or
   recompute re-admission);
 * ``drain-handoff`` — a draining replica hands the request back to the
   fleet queue (async scale-down);
-* ``completed`` / ``failed`` — terminal.
+* ``completed`` / ``failed`` — terminal; ``completed`` stamps
+  ``t_finish_host``.
+
+The engine stamps a host field once, at its first occurrence, so a
+preempted and re-admitted request keeps its first admission.
 
 Fleet-tier events (track ``FLEET_TRACK``) are timestamped on the fleet
 clock; engine-tier events on the owning replica's local clock (replicas
@@ -39,7 +65,7 @@ same subtraction as telemetry's ``latency``, so the two are bit-equal.
 The default recorder is :data:`NULL_RECORDER`, a no-op: with tracing
 disabled no event is ever buffered and engine/fleet stats are
 bit-identical to an uninstrumented run (gated by the ``obs`` bench
-section).
+section).  The host stamps are set with or without a recorder.
 
 **Straggler attribution** (:mod:`repro.obs.ledger`).  Each barrier step
 the fleet identifies the *gating* replica (the ``argmax`` of the

@@ -1,7 +1,11 @@
 """Per-request span recording and Chrome trace-event export.
 
 :class:`SpanRecorder` buffers lifecycle *point* events ``(track, rid,
-name, t, args)`` on the deterministic sim clock; the exporter derives
+name, t, args)``.  ``t`` is on the deterministic sim clock (the
+engine's modelled ``t_now``); a point may also carry ``host_s``, the
+host's ``time.perf_counter()`` when it was emitted, which the engine
+gives every point it emits and the export writes in ``args`` beside
+the modelled ``ts``.  The exporter derives
 duration spans from them (a ``request`` span per rid from ``queued`` to
 its terminal event, a ``decode`` span from first token to terminal) and
 writes Chrome trace-event JSON loadable in Perfetto / chrome://tracing.
@@ -44,7 +48,11 @@ class SpanRecorder:
         self.events: list[tuple] = []   # (track, rid, name, t, args)
 
     def point(self, track: int, rid: int, name: str, t: float,
-              **args) -> None:
+              host_s: float = None, **args) -> None:
+        """``t``: the modelled clock; ``host_s``: the host clock
+        (``time.perf_counter()``), kept in ``args``."""
+        if host_s is not None:
+            args["host_s"] = float(host_s)
         self.events.append((int(track), int(rid), name, float(t),
                             args or None))
 
@@ -63,7 +71,7 @@ class NullRecorder:
     events: tuple = ()
     n_events = 0
 
-    def point(self, track, rid, name, t, **args) -> None:
+    def point(self, track, rid, name, t, host_s=None, **args) -> None:
         pass
 
     def clear(self) -> None:
@@ -123,7 +131,8 @@ def to_chrome_trace(recorder) -> dict:
                                     "status": status}})
     return {"traceEvents": events, "displayTimeUnit": "ms",
             "otherData": {"generator": "repro.obs",
-                          "clock": "sim-seconds (ts in us)"}}
+                          "clock": "sim-seconds (ts in us); "
+                                   "args.host_s: host perf_counter s"}}
 
 
 def write_trace(recorder, path: str) -> dict:

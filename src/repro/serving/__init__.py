@@ -8,6 +8,7 @@ from .engine import (  # noqa: F401
     LoadSnapshot,
     ServeRequest,
     ServingEngine,
+    host_phases,
 )
 from .cache_backend import (  # noqa: F401
     CacheBackend,

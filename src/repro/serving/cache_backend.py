@@ -52,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import ModelConfig
+from ..obs.spans import span
 from ..models import (
     chunk_prefill_fn,
     decode_fn,
@@ -95,10 +96,10 @@ def _jitted_decode_full(cfg: ModelConfig, mesh):
 
     The cache argument is donated: the caller always replaces its cache
     with the returned one, so the old buffers can be reused in place."""
-    def f(p, c, t):
+    def slot_decode_full(p, c, t):
         logits, c2 = decode_fn(cfg, p, c, t, mesh=mesh)
         return jnp.argmax(logits, -1).astype(jnp.int32), c2
-    return jax.jit(f, donate_argnums=(1,))
+    return jax.jit(slot_decode_full, donate_argnums=(1,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -106,27 +107,27 @@ def _jitted_decode_compact(cfg: ModelConfig, mesh):
     """Compact decode: gather rows ``idx`` out of the flat cache, decode
     only those, scatter the updated rows back at ``dst``.  Padding rows
     carry ``dst == N`` so their writes are dropped."""
-    def f(p, cache, toks, idx, dst):
+    def slot_decode_compact(p, cache, toks, idx, dst):
         sub = gather_rows(cache, idx)
         logits, new_sub = decode_fn(cfg, p, sub, toks, mesh=mesh)
         return (jnp.argmax(logits, -1).astype(jnp.int32),
                 scatter_rows(cache, new_sub, dst))
-    return jax.jit(f, donate_argnums=(1,))
+    return jax.jit(slot_decode_compact, donate_argnums=(1,))
 
 
 @functools.lru_cache(maxsize=None)
 def _jitted_chunk_prefill(cfg: ModelConfig, mesh):
     """Chunked prefill over contiguous rows: gather the chunking slots'
     rows, advance one chunk, scatter back (pads at ``dst == N``)."""
-    def f(p, cache, toks, offs, clens, idx, dst):
+    def slot_chunk_prefill(p, cache, toks, offs, clens, idx, dst):
         sub = gather_rows(cache, idx)
         logits, new_sub = chunk_prefill_fn(cfg, p, sub, toks, offs, clens,
                                            mesh=mesh)
         return logits, scatter_rows(cache, new_sub, dst)
-    return jax.jit(f, donate_argnums=(1,))
+    return jax.jit(slot_chunk_prefill, donate_argnums=(1,))
 
 
-def _install_impl(cache, mini, src, dst):
+def install_prefill_rows(cache, mini, src, dst):
     """Copy rows ``src`` of the prefill mini cache into slots ``dst`` of
     the flat cache — one fused program instead of eager per-leaf
     gather/scatter (which dominated request admission cost).  Cache
@@ -146,26 +147,27 @@ def _install_impl(cache, mini, src, dst):
     return jax.tree.map(copy, cache, mini)
 
 
-_INSTALL = jax.jit(_install_impl, donate_argnums=(0,))
+_INSTALL = jax.jit(install_prefill_rows, donate_argnums=(0,))
 
 
 @functools.lru_cache(maxsize=None)
 def _jitted_paged_decode(cfg: ModelConfig, mesh, block_size: int,
                          attn_impl: str):
-    def f(p, kp, vp, tables, lengths, blk, off, toks):
+    def paged_decode_step(p, kp, vp, tables, lengths, blk, off, toks):
         return paged_decode_fn(cfg, p, kp, vp, tables, lengths, blk, off,
                                toks, block_size=block_size,
                                attn_impl=attn_impl, mesh=mesh)
-    return jax.jit(f, donate_argnums=(1, 2))
+    return jax.jit(paged_decode_step, donate_argnums=(1, 2))
 
 
 @functools.lru_cache(maxsize=None)
 def _jitted_paged_chunk(cfg: ModelConfig, mesh, block_size: int):
-    def f(p, kp, vp, tables, toks, offs, clens, wblk, woff):
+    def paged_chunk_prefill(p, kp, vp, tables, toks, offs, clens, wblk,
+                            woff):
         return paged_chunk_prefill_fn(cfg, p, kp, vp, tables, toks, offs,
                                       clens, wblk, woff,
                                       block_size=block_size, mesh=mesh)
-    return jax.jit(f, donate_argnums=(1, 2))
+    return jax.jit(paged_chunk_prefill, donate_argnums=(1, 2))
 
 
 # ----------------------------------------------------------------------
@@ -250,7 +252,7 @@ class SlotCacheBackend(CacheBackend):
     def write_prefill(self, mini_cache, src, dst, tokens=None,
                       chains=None) -> None:
         """One fused jitted gather/scatter over the whole admitted batch
-        and all cache leaves (see :func:`_install_impl`; the old cache is
+        and all cache leaves (see :func:`install_prefill_rows`; the old cache is
         donated).  ``tokens``/``chains`` are unused (the contiguous
         layout is not content-addressed)."""
         self.cache = _INSTALL(self.cache, mini_cache,
@@ -258,13 +260,15 @@ class SlotCacheBackend(CacheBackend):
                               jnp.asarray(dst, jnp.int32))
 
     def prefill_chunk(self, toks, offs, clens, slots) -> np.ndarray:
-        idx = np.maximum(slots, 0).astype(np.int32)
-        dst = np.where(slots >= 0, slots, self.N).astype(np.int32)
-        logits, self.cache = self._chunk(
-            self.params, self.cache, np.asarray(toks, np.int32),
-            np.asarray(offs, np.int32), np.asarray(clens, np.int32),
-            idx, dst)
-        return np.asarray(logits)
+        with span("serve.chunk.launch"):
+            idx = np.maximum(slots, 0).astype(np.int32)
+            dst = np.where(slots >= 0, slots, self.N).astype(np.int32)
+            logits, self.cache = self._chunk(
+                self.params, self.cache, np.asarray(toks, np.int32),
+                np.asarray(offs, np.int32), np.asarray(clens, np.int32),
+                idx, dst)
+        with span("serve.chunk.wait"):
+            return np.asarray(logits)
 
     def decode(self, slot_tokens, active_idx, bucket) -> np.ndarray:
         # numpy args go straight into the jitted calls: the jit dispatch
@@ -272,17 +276,21 @@ class SlotCacheBackend(CacheBackend):
         # per array (which dominated small-model decode steps), and
         # dtype canonicalization is identical either way.
         n = active_idx.size
-        if bucket >= self.N:
-            nxt_all, self.cache = self._decode_full(
-                self.params, self.cache, slot_tokens)
-            return np.asarray(nxt_all)[active_idx]
-        idx = np.zeros(bucket, dtype=np.int32)
-        idx[:n] = active_idx
-        dst = np.full(bucket, self.N, dtype=np.int32)  # pads: dropped
-        dst[:n] = active_idx
-        nxt_sub, self.cache = self._decode_compact(
-            self.params, self.cache, slot_tokens[idx], idx, dst)
-        return np.asarray(nxt_sub)[:n]
+        with span("serve.decode.launch"):
+            if bucket >= self.N:
+                nxt, self.cache = self._decode_full(
+                    self.params, self.cache, slot_tokens)
+                rows = active_idx
+            else:
+                idx = np.zeros(bucket, dtype=np.int32)
+                idx[:n] = active_idx
+                dst = np.full(bucket, self.N, dtype=np.int32)  # pads: dropped
+                dst[:n] = active_idx
+                nxt, self.cache = self._decode_compact(
+                    self.params, self.cache, slot_tokens[idx], idx, dst)
+                rows = slice(n)
+        with span("serve.decode.wait"):
+            return np.asarray(nxt)[rows]
 
     def release(self, slots) -> None:
         # rows are simply abandoned in place (stale KV is masked by
@@ -536,6 +544,14 @@ class PagedCacheBackend(CacheBackend):
             self.prefix.register(key, parent, span, bl[j])
 
     def prefill_chunk(self, toks, offs, clens, slots) -> np.ndarray:
+        with span("serve.chunk.launch"):
+            logits = self._launch_chunk(toks, offs, clens, slots)
+        with span("serve.chunk.wait"):
+            return np.asarray(logits)
+
+    def _launch_chunk(self, toks, offs, clens, slots):
+        """Grow the rows' block tables, build the write coordinates and
+        dispatch the chunk program; returns its logits unread."""
         bs = self.block_size
         nb, C = toks.shape
         for j in range(nb):
@@ -560,9 +576,19 @@ class PagedCacheBackend(CacheBackend):
             jnp.asarray(offs, jnp.int32), jnp.asarray(clens, jnp.int32),
             jnp.asarray(wblk), jnp.asarray(woff))
         self.kv.k_pool, self.kv.v_pool = kp, vp
-        return np.asarray(logits)
+        return logits
 
     def decode(self, slot_tokens, active_idx, bucket) -> np.ndarray:
+        n = active_idx.size
+        with span("serve.decode.launch"):
+            nxt = self._launch_decode(slot_tokens, active_idx, bucket)
+        with span("serve.decode.wait"):
+            return np.asarray(nxt)[:n]
+
+    def _launch_decode(self, slot_tokens, active_idx, bucket):
+        """Append each row's token slot, build the bucket's tables and
+        write coordinates and dispatch the decode program; returns its
+        next tokens unread."""
         n = active_idx.size
         self.kv.append_tokens(active_idx)
         lens = np.zeros(bucket, np.int32)
@@ -584,7 +610,7 @@ class PagedCacheBackend(CacheBackend):
             jnp.asarray(tables), jnp.asarray(lens), jnp.asarray(blk),
             jnp.asarray(off), jnp.asarray(toks))
         self.kv.k_pool, self.kv.v_pool = kp, vp
-        return np.asarray(nxt)[:n]
+        return nxt
 
     # -- memory pressure (engine-driven preemption) ---------------------
     def decode_block_demand(self, active_idx: np.ndarray) -> int:

@@ -53,6 +53,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
+import time
 from typing import Optional
 
 import jax
@@ -65,6 +67,7 @@ from ..core.metrics import step_imbalance
 from ..core.policies import Policy, SchedulerContext
 from ..core.workload import DriftModel, drift_for_family
 from ..models import decode_fn, prefill_fn, supports_paged_stack
+from ..obs.spans import span
 from ..obs.trace import NULL_RECORDER
 from .cache_backend import make_cache_backend
 from .preemption import (
@@ -88,9 +91,17 @@ class ServeRequest:
     worker: int = -1
     slot: int = -1
     generated: list = dataclasses.field(default_factory=list)
+    # modelled clock (the engine's t_now): deterministic, never a speed
     t_submit: float = 0.0
     t_first_token: float = float("nan")
     t_finish: float = float("nan")
+    # host clock (time.perf_counter()), each set once at its first
+    # occurrence: a preempted and re-admitted request keeps its first
+    # admission
+    t_admit_host: float = float("nan")
+    t_first_chunk_host: float = float("nan")
+    t_first_token_host: float = float("nan")
+    t_finish_host: float = float("nan")
     # per-request completion/error channel: "queued" -> "active" ->
     # "done" | "failed" (a preempted request returns to "queued").  A
     # failure (``error`` set) is terminal for THIS request only — the
@@ -174,15 +185,43 @@ class EngineConfig:
 @functools.lru_cache(maxsize=None)
 def _jitted_decode(cfg: ModelConfig, mesh):
     """Seed-path decode: full G*B batch, returns (logits, cache)."""
-    return jax.jit(lambda p, c, t: decode_fn(cfg, p, c, t, mesh=mesh))
+    def ref_decode_step(p, c, t):
+        return decode_fn(cfg, p, c, t, mesh=mesh)
+    return jax.jit(ref_decode_step)
 
 
 @functools.lru_cache(maxsize=None)
 def _jitted_prefill(cfg: ModelConfig, mesh, max_len: int):
     """Jitted prefill (vec path; the ref path keeps the seed's eager
     prefill).  Callers bucket the batch-size dim to bound recompiles."""
-    return jax.jit(functools.partial(prefill_fn, cfg, max_len=max_len,
-                                     mesh=mesh))
+    def sync_prefill(params, batch):
+        return prefill_fn(cfg, params, batch, max_len=max_len, mesh=mesh)
+    return jax.jit(sync_prefill)
+
+
+# lifecycle point -> the request's host-clock field it stamps (once)
+_HOST_STAMPS = {"admitted": "t_admit_host",
+                "prefill-chunk": "t_first_chunk_host",
+                "decode": "t_first_token_host",
+                "completed": "t_finish_host"}
+# a finished request's host-clock phases: name -> (from stamp, to stamp)
+HOST_PHASES = {"first_chunk": ("t_admit_host", "t_first_chunk_host"),
+               "prefill": ("t_first_chunk_host", "t_first_token_host"),
+               "decode": ("t_first_token_host", "t_finish_host")}
+
+
+def host_phases(requests) -> dict:
+    """Seconds on the host clock between the stamps of each finished
+    request, per phase of :data:`HOST_PHASES`: admission to its first
+    prefill chunk, the rest of its prefill to its first token, and its
+    decode to its finish.  A request without both stamps of a phase (no
+    chunk on the synchronous prefill path) is left out of that phase."""
+    done = [r for r in requests if r.status == "done"]
+    out = {}
+    for name, (a, b) in HOST_PHASES.items():
+        d = np.asarray([getattr(r, b) - getattr(r, a) for r in done], float)
+        out[name] = d[np.isfinite(d)]
+    return out
 
 
 def _decode_buckets(N: int) -> list[int]:
@@ -349,10 +388,24 @@ class ServingEngine:
                     "never be admitted")
         req.t_submit = self.t_now
         req.status = "queued"
-        if self._obs_rec.enabled:
-            self._obs_rec.point(self._obs_replica, req.rid, "queued",
-                                self.t_now, n_prompt=len(req.tokens))
+        self._mark(req, "queued", n_prompt=len(req.tokens))
         self.scheduler.submit(req)
+
+    def _mark(self, r: ServeRequest, name: str, **args) -> None:
+        """A lifecycle point of ``r``: stamp its host-clock field for
+        ``name`` (:data:`_HOST_STAMPS`) if not yet set, and record the
+        point, on the modelled clock with ``host_s``, when a recorder is
+        enabled."""
+        field = _HOST_STAMPS.get(name)
+        stamp = field is not None and math.isnan(getattr(r, field))
+        if not (stamp or self._obs_rec.enabled):
+            return
+        now = time.perf_counter()
+        if stamp:
+            setattr(r, field, now)
+        if self._obs_rec.enabled:
+            self._obs_rec.point(self._obs_replica, r.rid, name, self.t_now,
+                                host_s=now, **args)
 
     def _worker_of(self, slot: int) -> int:
         return slot // self.B
@@ -565,18 +618,13 @@ class ServingEngine:
                 resume_token = int(r.preempted.next_token)
                 resume_length = int(r.preempted.length)
                 r.preempted = None
-                if self._obs_rec.enabled:
-                    self._obs_rec.point(self._obs_replica, r.rid,
-                                        "resumed", self.t_now,
-                                        slot=slot, mode="recompute")
+                self._mark(r, "resumed", slot=slot, mode="recompute")
             elif self._paged and self.backend.prefix is not None:
                 done = self.backend.seed_chunk_prefix(
                     slot, toks, count=first_admit,
                     chain=self._prefix_chain(r, toks))
-            if self._obs_rec.enabled and resume_token is None:
-                self._obs_rec.point(self._obs_replica, r.rid,
-                                    "admitted", self.t_now,
-                                    worker=g, slot=slot, seeded=done)
+            if resume_token is None:
+                self._mark(r, "admitted", worker=g, slot=slot, seeded=done)
             self.slot_load[slot] = float(done)
             self.table.prefill_left[slot] = len(toks) - done
             self.scheduler.register_job(slot, r, toks, done=done,
@@ -595,9 +643,7 @@ class ServingEngine:
             slot = int(slots[i])
             st = r.preempted
             self.backend.swap_in(slot, st)
-            if self._obs_rec.enabled:
-                self._obs_rec.point(self._obs_replica, r.rid, "resumed",
-                                    self.t_now, slot=slot, mode="swap")
+            self._mark(r, "resumed", slot=slot, mode="swap")
             r.worker, r.slot = g, slot
             r.status = "active"
             self.slot_req[slot] = r
@@ -683,10 +729,7 @@ class ServingEngine:
         r.status = "queued"
         self.scheduler.requeue(r)
         self.preemptions += 1
-        if self._obs_rec.enabled:
-            self._obs_rec.point(self._obs_replica, r.rid, "preempted",
-                                self.t_now, slot=slot,
-                                mode=self.ec.preemption_mode)
+        self._mark(r, "preempted", slot=slot, mode=self.ec.preemption_mode)
 
     def drain(self) -> list:
         """Evict everything this engine holds for fleet-tier re-routing
@@ -734,9 +777,7 @@ class ServingEngine:
         self.table.release(np.asarray([slot]))
         self.backend.release(np.asarray([slot]))
         self.requests_failed += 1
-        if self._obs_rec.enabled:
-            self._obs_rec.point(self._obs_replica, r.rid, "failed",
-                                self.t_now)
+        self._mark(r, "failed")
 
     def _ensure_decode_capacity(self) -> None:
         """Preempt until the pool can serve this step's decode growth
@@ -785,42 +826,49 @@ class ServingEngine:
         backend, capacity for the planned chunks is secured *first* by
         preempting victims (a preempted victim may itself be a planned
         job, so the plan is rebuilt after every preemption)."""
-        plan = self.scheduler.plan_chunks()
-        if self._paged:
-            while True:
-                need = self.backend.chunk_block_demand(plan)
-                if need <= self.backend.free_blocks:
-                    break
-                if not self._preempt_one():
-                    raise MemoryError(
-                        f"KV pool exhausted with no preemptable victim: "
-                        f"prefill chunks need {need} blocks, "
-                        f"{self.backend.free_blocks} free of "
-                        f"{self.backend.n_blocks}")
-                plan = self.scheduler.plan_chunks()
+        with span("serve.plan"):
+            plan = self.scheduler.plan_chunks()
+            if self._paged:
+                while True:
+                    need = self.backend.chunk_block_demand(plan)
+                    if need <= self.backend.free_blocks:
+                        break
+                    if not self._preempt_one():
+                        raise MemoryError(
+                            f"KV pool exhausted with no preemptable "
+                            f"victim: prefill chunks need {need} blocks, "
+                            f"{self.backend.free_blocks} free of "
+                            f"{self.backend.n_blocks}")
+                    plan = self.scheduler.plan_chunks()
         if not plan:
             return 0
         rows = len(plan)
         nbp = next(b for b in self._buckets if b >= rows)
         C = self.scheduler.chunk
-        toks = np.zeros((nbp, C), dtype=np.int32)
-        offs = np.zeros(nbp, dtype=np.int32)
-        clens = np.zeros(nbp, dtype=np.int32)
-        slots = np.full(nbp, -1, dtype=np.int64)
+        total = sum(n for _, _, n in plan)
+        with span("serve.chunk", rows=rows, bucket=nbp, tokens=total,
+                  chunk=C):
+            toks = np.zeros((nbp, C), dtype=np.int32)
+            offs = np.zeros(nbp, dtype=np.int32)
+            clens = np.zeros(nbp, dtype=np.int32)
+            slots = np.full(nbp, -1, dtype=np.int64)
+            for j, (slot, off, n) in enumerate(plan):
+                job = self.scheduler.job(slot)
+                toks[j, :n] = job.tokens[off:off + n]
+                offs[j], clens[j], slots[j] = off, n, slot
+            logits = self.backend.prefill_chunk(toks, offs, clens, slots)
+        with span("serve.chunk.commit"):
+            self._commit_chunks(plan, logits)
+        return total
+
+    def _commit_chunks(self, plan: list, logits: np.ndarray) -> None:
+        """Advance each planned job by its chunk; a job whose prompt is
+        done takes its first token from ``logits`` (or, rebuilding a
+        recompute victim, its preserved next token)."""
         for j, (slot, off, n) in enumerate(plan):
             job = self.scheduler.job(slot)
-            toks[j, :n] = job.tokens[off:off + n]
-            offs[j], clens[j], slots[j] = off, n, slot
-        logits = self.backend.prefill_chunk(toks, offs, clens, slots)
-        total = 0
-        for j, (slot, off, n) in enumerate(plan):
-            total += n
-            job = self.scheduler.job(slot)
-            if self._obs_rec.enabled:
-                self._obs_rec.point(self._obs_replica,
-                                    self.slot_req[slot].rid,
-                                    "prefill-chunk", self.t_now,
-                                    slot=slot, offset=off, tokens=n)
+            self._mark(self.slot_req[slot], "prefill-chunk", slot=slot,
+                       offset=off, tokens=n)
             finished = self.scheduler.advance(slot, n)
             done = off + n
             self.slot_load[slot] = float(done)
@@ -855,14 +903,10 @@ class ServingEngine:
                 r.generated.append(first)
                 if np.isnan(r.t_first_token):
                     r.t_first_token = self.t_now
-                    if self._obs_rec.enabled:
-                        self._obs_rec.point(self._obs_replica, r.rid,
-                                            "decode", self.t_now,
-                                            slot=slot)
+                    self._mark(r, "decode", slot=slot)
                 if (len(r.generated) >= r.max_new_tokens
                         or first == r.eos_id):
                     self._finish_at_prefill(slot, r)
-        return total
 
     def _finish_at_prefill(self, slot: int, r: "ServeRequest") -> None:
         """A request whose budget (or eos) is already met by its first
@@ -873,9 +917,7 @@ class ServingEngine:
         self.slot_req[slot] = None
         self.table.release(np.asarray([slot]))
         self.backend.release(np.asarray([slot]))
-        if self._obs_rec.enabled:
-            self._obs_rec.point(self._obs_replica, r.rid, "completed",
-                                self.t_now, n_generated=len(r.generated))
+        self._mark(r, "completed", n_generated=len(r.generated))
 
     def _prefill_batch(self, items: list[tuple["ServeRequest", int]]) -> None:
         """Run prefill for admitted requests and write their cache slots.
@@ -959,24 +1001,16 @@ class ServingEngine:
                     # backend re-admits the slot below
                     length_fix.append((slot, int(r.preempted.length)))
                 r.preempted = None
-                if self._obs_rec.enabled:
-                    self._obs_rec.point(self._obs_replica, r.rid,
-                                        "resumed", self.t_now,
-                                        slot=slot, mode="recompute")
+                self._mark(r, "resumed", slot=slot, mode="recompute")
                 continue
-            if self._obs_rec.enabled:
-                self._obs_rec.point(self._obs_replica, r.rid,
-                                    "admitted", self.t_now,
-                                    worker=g, slot=slot, seeded=0)
+            self._mark(r, "admitted", worker=g, slot=slot, seeded=0)
             first_tok = int(first[i])
             self.slot_tokens[slot] = first_tok
             self.slot_age[slot] = 1
             r.generated.append(first_tok)
             if np.isnan(r.t_first_token):
                 r.t_first_token = self.t_now
-                if self._obs_rec.enabled:
-                    self._obs_rec.point(self._obs_replica, r.rid,
-                                        "decode", self.t_now, slot=slot)
+                self._mark(r, "decode", slot=slot)
             if (len(r.generated) >= r.max_new_tokens
                     or first_tok == r.eos_id):
                 done_slots.append((slot, r))
@@ -1023,82 +1057,94 @@ class ServingEngine:
         The returned info dict carries ``phase`` — the dominant work
         class of the step (``preempt`` > ``prefill`` > ``decode`` >
         ``idle``) — which the fleet's straggler attribution maps to an
-        idle cause when this replica gates a barrier step."""
-        p0 = self.preemptions
-        fresh, resumed = self._admit()
-        chunk_tokens = self._run_chunks() if self.scheduler.chunked else 0
-        vec = self.ec.engine_mode == "vec"
-        if self._paged:
-            # secure this step's decode growth (block crossings + COW)
-            # before the barrier: preempt victims rather than letting the
-            # allocator raise mid-decode
-            self._ensure_decode_capacity()
-        if vec:
-            active_idx = self.table.active_indices()
-            decode_idx = self.table.decode_indices() \
-                if self.scheduler.chunked else active_idx
-            n_active = active_idx.size
-        else:
-            decode_idx = [s for s, r in enumerate(self.slot_req)
-                          if r is not None]
-            n_active = len(decode_idx)
-        loads = self._loads()
-        lmax = float(loads.max()) if n_active else 0.0
-        dt = self.ec.step_overhead + self.ec.t_token * lmax
-        u = loads / lmax if lmax > 0 else np.zeros(self.G)
-        self.energy_j += dt * float(self.ec.power.power(u).sum())
-        imb = step_imbalance(loads) if n_active else 0.0
-        self.imbalance_sum += imb
-        self.t_now += dt
-        self.steps += 1
+        idle cause when this replica gates a barrier step.
 
-        n_decode = len(decode_idx)
-        if n_decode:
-            if vec:
-                self._decode_step_vec(np.asarray(decode_idx))
+        Each host phase runs inside a span of :mod:`repro.obs.spans`."""
+        with span("serve.step", waiting=len(self.wait),
+                  active=self.table.n_active):
+            p0 = self.preemptions
+            with span("serve.admit"):
+                fresh, resumed = self._admit()
+            chunk_tokens = self._run_chunks() if self.scheduler.chunked \
+                else 0
+            vec = self.ec.engine_mode == "vec"
+            if self._paged:
+                # secure this step's decode growth (block crossings +
+                # COW) before the barrier: preempt victims rather than
+                # letting the allocator raise mid-decode
+                with span("serve.reserve") as sp:
+                    p1 = self.preemptions
+                    self._ensure_decode_capacity()
+                    sp.set_metadata(preempted=self.preemptions - p1)
+            with span("serve.account"):
+                if vec:
+                    active_idx = self.table.active_indices()
+                    decode_idx = self.table.decode_indices() \
+                        if self.scheduler.chunked else active_idx
+                    n_active = active_idx.size
+                else:
+                    decode_idx = [s for s, r in enumerate(self.slot_req)
+                                  if r is not None]
+                    n_active = len(decode_idx)
+                loads = self._loads()
+                lmax = float(loads.max()) if n_active else 0.0
+                dt = self.ec.step_overhead + self.ec.t_token * lmax
+                u = loads / lmax if lmax > 0 else np.zeros(self.G)
+                self.energy_j += dt * float(self.ec.power.power(u).sum())
+                imb = step_imbalance(loads) if n_active else 0.0
+                self.imbalance_sum += imb
+                self.t_now += dt
+                self.steps += 1
+
+            n_decode = len(decode_idx)
+            if n_decode:
+                if vec:
+                    self._decode_step_vec(np.asarray(decode_idx))
+                else:
+                    self._decode_step_ref(decode_idx)
+            if self.ec.cache_backend == "paged":
+                self.kv_peak_bytes = max(self.kv_peak_bytes,
+                                         self.backend.resident_kv_bytes())
+            if self.preemptions > p0 or resumed:
+                phase = "preempt"
+            elif chunk_tokens or fresh:
+                phase = "prefill"
+            elif n_decode:
+                phase = "decode"
             else:
-                self._decode_step_ref(decode_idx)
-        if self.ec.cache_backend == "paged":
-            self.kv_peak_bytes = max(self.kv_peak_bytes,
-                                     self.backend.resident_kv_bytes())
-        if self.preemptions > p0 or resumed:
-            phase = "preempt"
-        elif chunk_tokens or fresh:
-            phase = "prefill"
-        elif n_decode:
-            phase = "decode"
-        else:
-            phase = "idle"
-        return {"t": self.t_now, "active": n_active,
-                "waiting": len(self.wait), "max_load": lmax,
-                "imbalance": imb, "decoded": n_decode,
-                "prefill_tokens": chunk_tokens,
-                "prefilling": self.scheduler.n_prefilling,
-                "phase": phase}
+                phase = "idle"
+            return {"t": self.t_now, "active": n_active,
+                    "waiting": len(self.wait),
+                    "prefill_tokens": chunk_tokens,
+                    "prefilling": self.scheduler.n_prefilling,
+                    "phase": phase}
 
     def _decode_step_ref(self, active: list[int]) -> None:
         """Seed decode path: always decode all G*B slots, per-slot loop."""
-        tokens = jnp.asarray(self.slot_tokens)    # ra: ignore[RA104] — ref oracle is deliberately eager
-        logits, self.cache = self._decode(self.params, self.cache, tokens)
-        nxt = np.asarray(jnp.argmax(logits, -1), dtype=np.int32)  # ra: ignore[RA104] — ref oracle is deliberately eager
-        for s in active:
-            r = self.slot_req[s]
-            tok = int(nxt[s])
-            r.generated.append(tok)
-            self.slot_tokens[s] = tok
-            self.tokens_out += 1
-            self.slot_load[s] += self.drift.increment(self.steps)
-            if (len(r.generated) >= r.max_new_tokens
-                    or tok == r.eos_id):
-                r.t_finish = self.t_now
-                r.status = "done"
-                if self._obs_rec.enabled:
-                    self._obs_rec.point(self._obs_replica, r.rid,
-                                        "completed", self.t_now,
-                                        n_generated=len(r.generated))
-                self.slot_req[s] = None
-                self.slot_load[s] = 0.0
-                self.table.active[s] = False
+        n = len(active)
+        with span("serve.decode", rows=n, bucket=self.slot_tokens.size,
+                  tokens=n):
+            tokens = jnp.asarray(self.slot_tokens)    # ra: ignore[RA104] — ref oracle is deliberately eager
+            logits, self.cache = self._decode(self.params, self.cache,
+                                              tokens)
+            nxt = np.asarray(jnp.argmax(logits, -1), dtype=np.int32)  # ra: ignore[RA104] — ref oracle is deliberately eager
+        with span("serve.decode.commit"):
+            for s in active:
+                r = self.slot_req[s]
+                tok = int(nxt[s])
+                r.generated.append(tok)
+                self.slot_tokens[s] = tok
+                self.tokens_out += 1
+                self.slot_load[s] += self.drift.increment(self.steps)
+                if (len(r.generated) >= r.max_new_tokens
+                        or tok == r.eos_id):
+                    r.t_finish = self.t_now
+                    r.status = "done"
+                    self._mark(r, "completed",
+                               n_generated=len(r.generated))
+                    self.slot_req[s] = None
+                    self.slot_load[s] = 0.0
+                    self.table.active[s] = False
 
     def _decode_step_vec(self, active_idx: np.ndarray) -> None:
         """Vectorized decode path: compact the active slots into the
@@ -1107,29 +1153,30 @@ class ServingEngine:
         block-table indirection for the paged backend)."""
         n = active_idx.size
         nb = next(b for b in self._buckets if b >= n)
-        nxt = self.backend.decode(self.slot_tokens, active_idx, nb)
+        with span("serve.decode", rows=n, bucket=nb, tokens=n):
+            nxt = self.backend.decode(self.slot_tokens, active_idx, nb)
 
-        self.slot_tokens[active_idx] = nxt
-        self.slot_load[active_idx] += self.drift.increment(self.steps)
-        self.slot_age[active_idx] += 1
-        self.tokens_out += n
-        for pos, s in enumerate(active_idx):
-            self.slot_req[s].generated.append(int(nxt[pos]))
-        done = ((self.slot_age[active_idx] >= self.slot_max_new[active_idx])
-                | (nxt.astype(np.int64) == self.slot_eos[active_idx]))
-        if done.any():
-            done_idx = active_idx[done]
-            for s in done_idx:
-                r = self.slot_req[s]
-                r.t_finish = self.t_now
-                r.status = "done"
-                if self._obs_rec.enabled:
-                    self._obs_rec.point(self._obs_replica, r.rid,
-                                        "completed", self.t_now,
-                                        n_generated=len(r.generated))
-                self.slot_req[s] = None
-            self.table.release(done_idx)
-            self.backend.release(done_idx)
+        with span("serve.decode.commit"):
+            self.slot_tokens[active_idx] = nxt
+            self.slot_load[active_idx] += self.drift.increment(self.steps)
+            self.slot_age[active_idx] += 1
+            self.tokens_out += n
+            for pos, s in enumerate(active_idx):
+                self.slot_req[s].generated.append(int(nxt[pos]))
+            done = ((self.slot_age[active_idx]
+                     >= self.slot_max_new[active_idx])
+                    | (nxt.astype(np.int64) == self.slot_eos[active_idx]))
+            if done.any():
+                done_idx = active_idx[done]
+                for s in done_idx:
+                    r = self.slot_req[s]
+                    r.t_finish = self.t_now
+                    r.status = "done"
+                    self._mark(r, "completed",
+                               n_generated=len(r.generated))
+                    self.slot_req[s] = None
+                self.table.release(done_idx)
+                self.backend.release(done_idx)
 
     def run(self, max_steps: int = 10_000) -> dict:
         """Step until all submitted requests finish."""

@@ -42,6 +42,7 @@ from typing import Callable, Optional
 import numpy as np
 
 from ..core.policies import Policy, SchedulerContext
+from ..obs.spans import span
 from .preemption import LIFOPreemption, PreemptContext, PreemptionPolicy
 from .slot_table import cap_assignment
 
@@ -129,9 +130,11 @@ class Scheduler:
         is *strict FCFS* — the first request that does not fit stops
         admission for the step (no head-of-line bypass), so an oversized
         pool-pressure wave degrades to waiting instead of to a
-        ``MemoryError`` mid-prefill."""
-        assignment = cap_assignment(
-            np.asarray(self.policy.assign(ctx)), caps)
+        ``MemoryError`` mid-prefill.  The policy's assignment (the BF-IO
+        solve) runs inside the ``serve.place`` span."""
+        with span("serve.place"):
+            placed = np.asarray(self.policy.assign(ctx))
+        assignment = cap_assignment(placed, caps)
         to_admit = []
         left = block_budget
         for pos, g in enumerate(assignment):
