@@ -225,13 +225,18 @@ def host_phases(requests) -> dict:
 
 
 def _decode_buckets(N: int) -> list[int]:
-    """Sub-batch sizes the compact decode path may run at.  A small
-    geometric ladder bounds jit recompiles while keeping the drain-phase
-    decode cost proportional to the active count."""
+    """Row counts the engine pads a program's batch to: the compact
+    decode, the chunk-prefill plan and the vec path's synchronous
+    prefill.  A geometric ladder, x4 down to 4 and then halving to 1,
+    bounds jit recompiles while keeping a program's cost proportional to
+    its live rows: a one- or two-row chunk plan runs at 1 or 2 rows."""
     buckets = {N}
     b = N
     while b > 4:
         b = max(4, (b + 3) // 4)
+        buckets.add(b)
+    while b > 1:
+        b //= 2
         buckets.add(b)
     return sorted(buckets)
 

@@ -24,6 +24,7 @@ from repro.serving import (
     SlotTable,
     cap_assignment,
 )
+from repro.serving.engine import _decode_buckets
 
 CFG = ModelConfig(name="tiny", family="dense", n_layers=2, d_model=64,
                   n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=128,
@@ -105,6 +106,19 @@ class TestRefVecParity:
             assert sa[k] == sb[k]
         for ra, rb in zip(reqs_a, reqs_b):
             assert ra.generated == rb.generated
+
+
+@pytest.mark.parametrize("N", [1, 3, 4, 8, 32, 64])
+def test_bucket_ladder_runs_from_one_row_to_the_slots(N):
+    """The row ladder a program's batch is padded to starts at one row,
+    holds the slot count and climbs at most x4 a rung, so a plan of one
+    or two live rows runs a one- or two-row program."""
+    ladder = _decode_buckets(N)
+    assert ladder[0] == 1 and ladder[-1] == N
+    assert ladder == sorted(set(ladder))
+    assert all(b <= 4 * a for a, b in zip(ladder, ladder[1:]))
+    if N == 32:
+        assert ladder == [1, 2, 4, 8, 32]
 
 
 class TestEosEarlyStop:

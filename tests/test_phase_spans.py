@@ -287,3 +287,63 @@ def test_generations_and_stats_unchanged_under_a_profiler(setup, traced):
     assert [r.generated for r in reqs_on] == \
         [r.generated for r in reqs_off]
     assert eng_on.stats() == eng_off.stats()
+
+
+def _ladder_run(setup, monkeypatch, buckets=None):
+    """Serve one fixed set of prompts on 32 paged slots, recording each
+    chunk program call as (bucket rows, live rows, live rows' logits)
+    and each ``serve.chunk`` span's args; ``buckets`` replaces the
+    engine's row ladder."""
+    params, mesh = setup
+    spans = []
+
+    def recording_span(name, **args):
+        if name == "serve.chunk":
+            spans.append(args)
+        return span(name, **args)
+    monkeypatch.setattr(engine_mod, "span", recording_span)
+    eng = ServingEngine(
+        CFG, params,
+        EngineConfig(**dict(PAGED, n_workers=4, slots_per_worker=8)),
+        make_policy("bfio_h0"), mesh=mesh)
+    if buckets is not None:
+        eng._buckets = buckets
+    calls = []
+    chunk = eng.backend.prefill_chunk
+
+    def prefill_chunk(toks, offs, clens, slots):
+        logits = chunk(toks, offs, clens, slots)
+        live = slots >= 0
+        calls.append((toks.shape[0], int(live.sum()), logits[live]))
+        return logits
+    eng.backend.prefill_chunk = prefill_chunk
+    # an 8-token budget: 9 then 7 share a step (two rows), 16 takes two
+    # one-row steps, then 4, 3 and the head of 12 share one (three rows)
+    rng = np.random.default_rng(5)
+    reqs = [ServeRequest(rid=i, tokens=rng.integers(1, 128, size=n),
+                         max_new_tokens=int(rng.integers(3, 12)))
+            for i, n in enumerate([9, 7, 16, 4, 3, 12])]
+    for r in reqs:
+        eng.submit(r)
+    eng.run(max_steps=5000)
+    return eng, reqs, calls, spans
+
+
+def test_chunk_program_runs_at_the_plans_rows_with_unchanged_tokens(
+        setup, monkeypatch):
+    eng, reqs, calls, spans = _ladder_run(setup, monkeypatch)
+    assert eng._buckets == [1, 2, 4, 8, 32]
+    for bucket, live, _ in calls:
+        assert bucket == next(b for b in eng._buckets if b >= live)
+    assert {live for _, live, _ in calls} >= {1, 2, 3}
+    assert [(a["bucket"], a["rows"]) for a in spans] == \
+        [(bucket, live) for bucket, live, _ in calls]
+
+    # the same requests with every program padded to at least four rows
+    _, reqs4, calls4, _ = _ladder_run(setup, monkeypatch, [4, 8, 32])
+    assert {bucket for bucket, _, _ in calls4} == {4}
+    assert [r.generated for r in reqs] == [r.generated for r in reqs4]
+    assert len(calls) == len(calls4)
+    for (_, live, logits), (_, live4, logits4) in zip(calls, calls4):
+        assert live == live4
+        np.testing.assert_allclose(logits, logits4, rtol=1e-5, atol=1e-5)
