@@ -6,6 +6,8 @@ prints one JSON result line.  Everything a cell needs is found by name:
 ``configs/<config>.json`` (model and engine settings),
 ``traffic/<mix>.json`` (the mix's parameters, read by
 :mod:`bench.traffic`), ``metrics/<metric>.py`` (one reader per
-per-layer metric) and ``references/<reference>.py`` (the plain float32
-model that decides ``correct``).
+per-layer metric), ``references/<reference>.py`` (the plain float32
+model that decides ``correct``) and ``archs/<model_type>.py`` (the
+mapping onto the program, the counts and the CPU cell's sizes of one
+architecture).
 """

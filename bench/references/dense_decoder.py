@@ -14,6 +14,8 @@ following the recipe the configuration's weights are made by (a
 truncated normal of scale 1/sqrt(fan-in) per matrix, a normal embedding
 over sqrt(hidden); the key tree below), and computes with them in
 float32.  It imports nothing of the program and takes nothing it made.
+``weights`` gives them whole, in the program's parameter tree, for the
+test that holds the two draws equal.
 
 ``served_gaps``: for each request (prompt, served tokens), run the whole
 sequence once and read, at every served position, how far the served
@@ -185,6 +187,30 @@ def _check(cfg: dict) -> None:
           and not cfg["tie_word_embeddings"])
     if not ok:
         raise ValueError(f"dense_decoder has no path for {cfg['name']}")
+
+
+def weights(cfg: dict, seed: int) -> dict:
+    """The float32 weights this module draws from ``seed``, whole and in
+    the program's parameter tree (each block's leaves stacked over
+    layers); for tests at a small size, since the forward draws one
+    layer at a time."""
+    _check(cfg)
+    d, n, vocab = (cfg["hidden_size"], cfg["num_hidden_layers"],
+                   cfg["vocab_size"])
+    keys = _root_keys(seed)
+    layers = [_layer_weights(lk, _dims(cfg)) for lk in _layer_keys(seed, n)]
+    attn = ("wq", "wk", "wv", "wo")
+
+    def stacked(names):
+        return {k: jnp.stack([w[k] for w in layers]) for k in names}
+
+    return {"embed": _embed_table(keys[0], vocab, d).astype(jnp.float32),
+            "lm_head": _head(keys[1], vocab, d),
+            "final_norm": jnp.ones((d,), jnp.float32),
+            "blocks": {"attn": stacked(attn),
+                       "ffn": stacked(layers[0].keys() - set(attn)),
+                       "norm1": jnp.ones((n, d), jnp.float32),
+                       "norm2": jnp.ones((n, d), jnp.float32)}}
 
 
 def _padded(n: int, limit: int, step: int = 1024) -> int:

@@ -4,7 +4,9 @@ A cell (``workloads`` entry) resolves to ``configs/<config>.json``,
 ``traffic/<traffic>.json`` and ``limits/<cell>.json`` (the limits of
 its ``correct``); a metric to its reader ``metrics/<metric>.py``, which
 defines ``read(run) -> float | None``; a configuration's reference to
-``references/<reference>.py``, which defines ``served_gaps``.
+``references/<reference>.py``, which defines ``served_gaps``,
+``control_gaps`` and ``weights``; its ``model_type`` to
+``archs/<model_type>.py`` (``system.arch``).
 """
 from __future__ import annotations
 
@@ -15,6 +17,11 @@ from pathlib import Path
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
+
+# bytes of one element, as the program stores it
+KV_BYTES = 2          # bfloat16 keys and values
+W_BYTES = 2           # bfloat16 matrices
+NORM_BYTES = 4        # float32 norm scales
 
 
 @dataclasses.dataclass(frozen=True)
@@ -45,6 +52,11 @@ def head_dim(cfg: dict) -> int:
     one, else hidden size over heads (as Mistral and Llama derive it)."""
     return cfg.get("head_dim") or \
         cfg["hidden_size"] // cfg["num_attention_heads"]
+
+
+def no_path(cfg: dict) -> ValueError:
+    return ValueError(f"{cfg['name']}: the program has no path for this "
+                      "configuration as its file states it")
 
 
 def load_benchmark() -> dict:

@@ -1,52 +1,39 @@
 """The system under test, built from a configuration file.
 
-The only module of the benchmark that imports the program (``repro``,
-from ``src/``): it maps a ``configs/<name>.json`` onto the program's
-``ModelConfig`` and ``EngineConfig``, draws the weights with the
-program's own ``init_serving_params`` and builds a ``ServingEngine``.
-A key the program cannot run as the file states is an error.
+This module and the architecture modules under ``archs/`` are the only
+benchmark code that imports the program (``repro``, from ``src/``).  A
+``configs/<name>.json`` is mapped onto the program's ``ModelConfig`` by
+``archs/<model_type>.py`` (``arch``) and onto its ``EngineConfig`` here;
+the weights are drawn with the program's own ``init_serving_params`` and
+a ``ServingEngine`` is built on them.  A key the program cannot run as
+the file states is an error.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib
 
-from .spec import head_dim
+from .spec import BENCH, no_path
 
-# the file's hidden_act -> the program's mlp_variant
-_MLP = {"silu": "swiglu"}
 _ENGINE_ONLY = ("policy",)
-# architectures whose layer equations the program runs: pre-norm RMSNorm,
-# grouped-query attention with full rotary positions and no biases
-_ARCH = ("mistral", "llama")
 
 
-def _check(cfg: dict) -> None:
-    """Refuse a file that states something the program cannot run."""
-    ok = (cfg["model_type"] in _ARCH and cfg["hidden_act"] in _MLP
-          and cfg.get("sliding_window") is None
-          and not cfg.get("attention_bias", False)
-          and not cfg.get("mlp_bias", False)
-          and cfg.get("rope_scaling") is None
-          and cfg["torch_dtype"] == "bfloat16")
-    if not ok:
-        raise ValueError(f"{cfg['name']}: the program has no path for this "
-                         "configuration as its file states it")
+def arch(cfg: dict):
+    """The module ``archs/<model_type>.py`` of a configuration file; a
+    name with a leading ``_`` (such as the package's ``__init__``) is no
+    architecture."""
+    name = cfg["model_type"]
+    if not (name.isidentifier() and not name.startswith("_")
+            and (BENCH / "archs" / f"{name}.py").is_file()):
+        raise no_path(cfg)
+    return importlib.import_module(f"bench.archs.{name}")
 
 
 def model_config(cfg: dict):
     """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-
-    _check(cfg)
-    return ModelConfig(
-        name=cfg["name"], family="dense",
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], head_dim=head_dim(cfg),
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        rope_theta=cfg["rope_theta"], norm_eps=cfg["rms_norm_eps"],
-        mlp_variant=_MLP[cfg["hidden_act"]], dtype=cfg["torch_dtype"],
-        tie_embeddings=cfg["tie_word_embeddings"], source=cfg["source"])
+    a = arch(cfg)
+    a.check(cfg)
+    return a.model_config(cfg)
 
 
 def engine_config(cfg: dict):

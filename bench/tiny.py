@@ -1,4 +1,5 @@
-"""A tiny cell for the CPU tests: a two-layer dense decoder through the
+"""A tiny cell for the CPU tests: a configuration cut to its
+architecture's ``TINY`` sizes (``archs/<model_type>.py``), through the
 same harness, engine settings and reference as the benchmark's cells.
 
 The paged decode runs the ``gather`` path here (the Pallas kernel in
@@ -8,11 +9,9 @@ from __future__ import annotations
 
 import json
 
-from . import spec
+from . import spec, system
 
-TINY = dict(hidden_size=64, num_hidden_layers=2, num_attention_heads=4,
-            num_key_value_heads=2, head_dim=16, intermediate_size=128,
-            vocab_size=512, max_position_embeddings=256)
+BASE = "mistral-7b-v0.3-h16"
 MIX = {"arrivals": {"process": "poisson", "rate_per_s": 20.0},
        "prompt": {"dist": "lognormal", "median": 40, "sigma": 0.8,
                   "min": 8, "max": 160},
@@ -25,16 +24,16 @@ MIX = {"arrivals": {"process": "poisson", "rate_per_s": 20.0},
 LIMIT = 0.05
 
 
-def config(base: str = "mistral-7b-v0.3-h16", **engine) -> dict:
+def config(base: str = BASE, **engine) -> dict:
     cfg = json.loads((spec.BENCH / "configs" / f"{base}.json").read_text())
-    cfg.update(TINY)
+    cfg.update(system.arch(cfg).TINY, max_position_embeddings=256)
     cfg["engine"] = dict(cfg["engine"], max_seq_len=256,
                          paged_attn_impl="gather", paged_pool_blocks=256,
                          prefill_chunk=32, **engine)
     return cfg
 
 
-def cell(base: str = "mistral-7b-v0.3-h16", per_layer=()) -> spec.Cell:
+def cell(base: str = BASE, per_layer=()) -> spec.Cell:
     bench = spec.load_benchmark()
     e2e = [m for m in bench["end_to_end"]
            if m["name"] in ("setup_s", "ttft_p50_s", "itl_p99_ms")]
