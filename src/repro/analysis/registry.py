@@ -158,13 +158,14 @@ DEFAULT_REGISTRY = Registry(
             ref="_decode_step_ref", vec="_decode_step_vec",
             # the seed path drives the flat cache + full-batch decode
             # directly; the vec path compacts through the backend seam
-            # and the slot-table scalar mirrors
+            # and the slot-table scalar mirrors, and records the backend
+            # program's expert counters on its span
             allow_ref=frozenset({
                 "attr:cache", "attr:params", "attr:_decode",
             }),
             allow_vec=frozenset({
                 "attr:backend", "attr:_buckets", "attr:slot_age",
-                "attr:slot_max_new", "attr:slot_eos",
+                "attr:slot_max_new", "attr:slot_eos", "attr:_record_moe",
             }),
         ),
         RefVecPair(

@@ -4,7 +4,9 @@ Every assigned architecture is expressed as a ``ModelConfig``; reduced smoke
 variants are derived with ``smoke_variant``.  Families:
 
   dense   — decoder-only transformer (GQA, RoPE, SwiGLU)
-  moe     — dense attention + top-k routed expert FFN (expert parallel)
+  moe     — dense attention + top-k routed expert FFN (expert parallel);
+            ``experts_held`` experts from ``expert_offset`` live on this
+            chip (0 = all), the router stays ``n_experts`` wide
   ssm     — xLSTM-style recurrent blocks (mLSTM / sLSTM), no KV cache
   hybrid  — Mamba2 blocks with a periodically applied *shared* attention
             block (Zamba2)
@@ -37,6 +39,8 @@ class ModelConfig:
     moe_d_ff: int = 0                # per-expert hidden dim
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01  # load-balance auxiliary loss
+    experts_held: int = 0            # experts this chip holds (0 = all)
+    expert_offset: int = 0           # index of the first held expert
     # --- SSM / hybrid ---
     ssm_state: int = 0               # N: state size per head (mamba2)
     ssm_heads: int = 0               # number of SSM heads (0 -> n_heads)
@@ -48,6 +52,7 @@ class ModelConfig:
     rope_theta: float = 1e4
     qkv_bias: bool = False
     sliding_window: int = 0          # 0 = full causal attention
+    qk_norm: bool = False            # RMSNorm of q and k per head, pre-RoPE
     # --- encoder-decoder (audio) ---
     encoder_layers: int = 0
     encoder_seq: int = 0             # precomputed frame-embedding length
@@ -67,6 +72,11 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def n_held(self) -> int:
+        """Experts whose weights this chip holds."""
+        return self.experts_held or self.n_experts
 
     @property
     def d_inner(self) -> int:

@@ -124,8 +124,8 @@ class ShardingRules:
             heads = kv_heads = hd = None
 
         # MoE sharding variant (EP vs TP-within-expert) — must agree with
-        # repro.models.moe.moe_ffn's ep_mode switch
-        if div(cfg.n_experts):
+        # repro.models.moe's ep_mode switch (over the experts held)
+        if div(cfg.n_held):
             experts, expert_mlp = "model", None
         elif cfg.is_moe and div(cfg.moe_d_ff):
             experts, expert_mlp = None, "model"

@@ -36,7 +36,7 @@ from .layers import (
     rms_norm,
     swiglu,
 )
-from .moe import moe_ffn
+from .moe import moe_ffn, moe_ffn_dropless
 
 PyTree = Any
 
@@ -59,6 +59,9 @@ def _attn_init(key, cfg: ModelConfig, *, cross: bool = False,
         "wo": dense_init(ks[3], (hq, hd, d), ("heads", "hd", "embed"),
                          scale=1.0 / np.sqrt(hq * hd), dtype=dtype),
     }
+    if cfg.qk_norm:
+        p["q_norm"] = norm_init(hd, ("hd",))
+        p["k_norm"] = norm_init(hd, ("hd",))
     if cfg.qkv_bias:
         p["bq"] = Param(jnp.zeros((hq, hd), dtype), ("heads", "hd"))
         p["bk"] = Param(jnp.zeros((hkv, hd), dtype), ("kv_heads", "hd"))
@@ -86,17 +89,25 @@ def _mlp_forward(cfg: ModelConfig, p, x):
 
 
 def _moe_init(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
+    """The router over all ``n_experts`` and the held experts' weights.
+
+    Expert ``e`` is drawn from ``fold_in(key, e)``, so a chip's held
+    experts are the same values as those rows of the whole layer."""
     d, f, E = cfg.d_model, cfg.moe_d_ff, cfg.n_experts
     ks = jax.random.split(key, 4)
+    held = jnp.arange(cfg.expert_offset, cfg.expert_offset + cfg.n_held)
+
+    def experts(k, shape, axes):
+        draw = jax.vmap(lambda e: dense_init(jax.random.fold_in(k, e),
+                                             shape, axes, dtype=dtype).value)
+        return Param(draw(held), ("experts",) + axes)
+
     return {
         "router": dense_init(ks[0], (d, E), ("embed", "experts"),
                              dtype=jnp.float32),
-        "w1": dense_init(ks[1], (E, d, f), ("experts", "embed", "expert_mlp"),
-                         dtype=dtype),
-        "w3": dense_init(ks[2], (E, d, f), ("experts", "embed", "expert_mlp"),
-                         dtype=dtype),
-        "w2": dense_init(ks[3], (E, f, d), ("experts", "expert_mlp", "embed"),
-                         scale=1.0 / np.sqrt(f), dtype=dtype),
+        "w1": experts(ks[1], (d, f), ("embed", "expert_mlp")),
+        "w3": experts(ks[2], (d, f), ("embed", "expert_mlp")),
+        "w2": experts(ks[3], (f, d), ("expert_mlp", "embed")),
     }
 
 
@@ -290,6 +301,25 @@ def init_params(cfg: ModelConfig, key) -> PyTree:
 # Block forward functions
 # ==========================================================================
 
+def _head_norm(cfg: ModelConfig, ap, name: str, t):
+    """Qwen3's QK-norm (``name`` ``q_norm`` or ``k_norm``): RMSNorm of
+    each head of q or k over ``head_dim`` with its own scale, before
+    RoPE; ``t`` unchanged without ``cfg.qk_norm``."""
+    return rms_norm(t, ap[name], cfg.norm_eps) if cfg.qk_norm else t
+
+
+def _ffn(cfg: ModelConfig, p, h, *, valid, mesh, batch_axes):
+    """The FFN half of an attention block on the serving paths: the
+    dense MLP, or the dropless expert layer over the ``valid`` tokens
+    (a thunk, so that dense programs build no mask).  Returns (y,
+    counters): counters as ``moe.MOE_COUNTERS``, None for a dense MLP."""
+    if not cfg.is_moe:
+        return _mlp_forward(cfg, p["ffn"], h), None
+    return moe_ffn_dropless(h, valid(), p["ffn"], k=cfg.experts_per_token,
+                            mesh=mesh, batch_axes=batch_axes,
+                            expert_offset=cfg.expert_offset)
+
+
 def _attn_forward(cfg: ModelConfig, p, x, *, sin, cos, mode: str,
                   cache=None, lengths=None, q_offset=0, mesh=None,
                   batch_axes=("data",), cross_kv=None, enc_lengths=None,
@@ -301,8 +331,8 @@ def _attn_forward(cfg: ModelConfig, p, x, *, sin, cos, mode: str,
     q = linear(h, ap["wq"], ap.get("bq"))          # (B, S, Hq, hd)
     k = linear(h, ap["wk"], ap.get("bk"))          # (B, S, Hkv, hd)
     v = linear(h, ap["wv"], ap.get("bv"))
-    q = apply_rope(q, sin, cos)
-    k = apply_rope(k, sin, cos)
+    q = apply_rope(_head_norm(cfg, ap, "q_norm", q), sin, cos)
+    k = apply_rope(_head_norm(cfg, ap, "k_norm", k), sin, cos)
 
     new_cache = None
     if mode == "decode":
@@ -352,13 +382,20 @@ def _attn_forward(cfg: ModelConfig, p, x, *, sin, cos, mode: str,
                        cp["attn"]["wo"].reshape(-1, cfg.d_model))
 
     h2 = rms_norm(x, p["norm2"], cfg.norm_eps)
-    if cfg.is_moe:
+    if cfg.is_moe and mode == "train":
         y, aux = moe_ffn(h2, p["ffn"], n_experts=cfg.n_experts,
                          k=cfg.experts_per_token, mesh=mesh,
                          batch_axes=batch_axes,
-                         capacity_factor=cfg.capacity_factor)
+                         capacity_factor=cfg.capacity_factor,
+                         expert_offset=cfg.expert_offset)
     else:
-        y = _mlp_forward(cfg, p["ffn"], h2)
+        # serving: a prefill's padding columns route nowhere
+        def valid():
+            if mode == "prefill" and lengths is not None:
+                return jnp.arange(x.shape[1])[None, :] < lengths[:, None]
+            return jnp.ones(x.shape[:2], bool)
+        y, _ = _ffn(cfg, p, h2, valid=valid, mesh=mesh,
+                    batch_axes=batch_axes)
     return x + y, new_cache, aux
 
 
@@ -854,26 +891,51 @@ def _chunk_qkv(cfg: ModelConfig, p, xx, sin, cos):
     """Pre-attention half of an attn block: norm + q/k/v projection + rope."""
     h = rms_norm(xx, p["norm1"], cfg.norm_eps)
     ap = p["attn"]
-    q = apply_rope(linear(h, ap["wq"], ap.get("bq")), sin, cos)
-    k = apply_rope(linear(h, ap["wk"], ap.get("bk")), sin, cos)
+    q = apply_rope(_head_norm(cfg, ap, "q_norm",
+                              linear(h, ap["wq"], ap.get("bq"))), sin, cos)
+    k = apply_rope(_head_norm(cfg, ap, "k_norm",
+                              linear(h, ap["wk"], ap.get("bk"))), sin, cos)
     v = linear(h, ap["wv"], ap.get("bv"))
     return q, k, v
 
 
-def _chunk_finish(cfg: ModelConfig, p, xx, o, *, mesh, batch_axes):
-    """Post-attention half: output projection + (MoE-)FFN residual."""
+def _chunk_finish(cfg: ModelConfig, p, xx, o, *, valid, mesh, batch_axes):
+    """Post-attention half: output projection + (MoE-)FFN residual.
+    Returns (xx, counters) as ``_ffn``."""
     ap = p["attn"]
     xx = xx + linear(o.reshape(o.shape[:-2] + (-1,)),
                      ap["wo"].reshape(-1, cfg.d_model))
     h2 = rms_norm(xx, p["norm2"], cfg.norm_eps)
-    if cfg.is_moe:
-        y, _ = moe_ffn(h2, p["ffn"], n_experts=cfg.n_experts,
-                       k=cfg.experts_per_token, mesh=mesh,
-                       batch_axes=batch_axes,
-                       capacity_factor=cfg.capacity_factor)
-    else:
-        y = _mlp_forward(cfg, p["ffn"], h2)
-    return xx + y
+    y, counters = _ffn(cfg, p, h2, valid=valid, mesh=mesh,
+                       batch_axes=batch_axes)
+    return xx + y, counters
+
+
+def _serve_blocks(cfg: ModelConfig, blocks):
+    """(xs, unpack): the stacked blocks as a serving scan's xs, and the
+    function that makes one step's slice a layer's params.  An expert
+    stack keeps its experts' weights out of the xs, whole, and the scan
+    carries each layer's index in their place: the grouped matmul then
+    reads the layer's hit experts in place, where a per-layer slice of
+    the stack is a copy of all of them.  Dense blocks pass unchanged."""
+    if not cfg.is_moe:
+        return blocks, lambda p: p
+    ffn = blocks["ffn"]
+    experts = {n: ffn[n] for n in ("w1", "w3", "w2")}
+    rest = dict(blocks, ffn={"router": ffn["router"]})
+
+    def unpack(xs):
+        p, layer = xs
+        return dict(p, ffn=dict(p["ffn"], layer=layer, **experts))
+
+    layers = jnp.arange(ffn["router"].shape[0], dtype=jnp.int32)
+    return (rest, layers), unpack
+
+
+def _layer_sums(counters):
+    """A scan's per-layer expert counters (layers, 3) summed over the
+    layers; None for a dense stack."""
+    return None if counters is None else counters.sum(0)
 
 
 def chunk_prefill_fn(cfg: ModelConfig, params, cache, tokens, offsets,
@@ -902,16 +964,23 @@ def chunk_prefill_fn(cfg: ModelConfig, params, cache, tokens, offsets,
                      posmat, L)
     bidx = jnp.arange(n)
 
+    def valid():
+        return jnp.arange(C)[None, :] < chunk_lens[:, None]
+
+    blocks, unpack = _serve_blocks(cfg, params["blocks"])
+
     def layer(xx, xs):
         p, c = xs
+        p = unpack(p)
         q, k, v = _chunk_qkv(cfg, p, xx, sin, cos)
         kc = c["k"].at[bidx[:, None], wpos].set(k.astype(c["k"].dtype))
         vc = c["v"].at[bidx[:, None], wpos].set(v.astype(c["v"].dtype))
         o = attn_lib.chunk_attention(q, kc, vc, q_pos=posmat, kv_len=kv_len)
-        xx = _chunk_finish(cfg, p, xx, o, mesh=mesh, batch_axes=batch_axes)
+        xx, _ = _chunk_finish(cfg, p, xx, o, valid=valid, mesh=mesh,
+                              batch_axes=batch_axes)
         return xx, {"k": kc, "v": vc}
 
-    x, ncs = jax.lax.scan(layer, x, (params["blocks"], cache["blocks"]))
+    x, ncs = jax.lax.scan(layer, x, (blocks, cache["blocks"]))
     idx = jnp.clip(chunk_lens - 1, 0, C - 1)
     x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
     new_cache = dict(cache)
@@ -952,7 +1021,10 @@ def paged_decode_fn(cfg: ModelConfig, params, k_pool, v_pool, tables,
         blocks via scalar-prefetched block tables and never materializes
         the contiguous view.
 
-    Returns (next_tokens (n,) int32 greedy, k_pool, v_pool).
+    Returns (next_tokens (n,) int32 greedy, k_pool, v_pool, counters):
+    counters (3,) int32, the expert layers' ``moe.MOE_COUNTERS`` summed
+    over layers (padding rows, ``lengths == 0``, route nowhere); None
+    for a dense model.
     """
     _require_paged_stack(cfg, "paged_decode_fn")
     if attn_impl not in ("gather", "ref", "pallas"):
@@ -965,8 +1037,11 @@ def paged_decode_fn(cfg: ModelConfig, params, k_pool, v_pool, tables,
     bt = jnp.clip(tables, 0, nb_pool - 1)
     L = tables.shape[1] * block_size
 
+    blocks, unpack = _serve_blocks(cfg, params["blocks"])
+
     def layer(xx, xs):
         p, kp, vp = xs
+        p = unpack(p)
         q, k, v = _chunk_qkv(cfg, p, xx, sin, cos)
         kp = kp.at[blk, off].set(k[:, 0].astype(kp.dtype))
         vp = vp.at[blk, off].set(v[:, 0].astype(vp.dtype))
@@ -984,13 +1059,15 @@ def paged_decode_fn(cfg: ModelConfig, params, k_pool, v_pool, tables,
             kc = _paged_contiguous(kp, bt, n, L)
             vc = _paged_contiguous(vp, bt, n, L)
             o = attn_lib.decode_attention(q[:, 0], kc, vc, lengths)[:, None]
-        xx = _chunk_finish(cfg, p, xx, o, mesh=mesh, batch_axes=batch_axes)
-        return xx, (kp, vp)
+        xx, counters = _chunk_finish(cfg, p, xx, o,
+                                     valid=lambda: lengths[:, None] > 0,
+                                     mesh=mesh, batch_axes=batch_axes)
+        return xx, (kp, vp, counters)
 
-    x, (kps, vps) = jax.lax.scan(layer, x, (params["blocks"], k_pool,
-                                            v_pool))
+    x, (kps, vps, counters) = jax.lax.scan(
+        layer, x, (blocks, k_pool, v_pool))
     nxt = jnp.argmax(_lm_logits(cfg, params, x[:, 0]), -1).astype(jnp.int32)
-    return nxt, kps, vps
+    return nxt, kps, vps, _layer_sums(counters)
 
 
 def paged_chunk_prefill_fn(cfg: ModelConfig, params, k_pool, v_pool, tables,
@@ -1002,7 +1079,8 @@ def paged_chunk_prefill_fn(cfg: ModelConfig, params, k_pool, v_pool, tables,
 
     wblk/woff: (n, C) physical (block, offset) of every chunk token
     (padding columns carry ``wblk == n_blocks`` -> dropped writes).
-    Returns (last_logits (n, V), k_pool, v_pool).
+    Returns (last_logits (n, V), k_pool, v_pool, counters): counters as
+    :func:`paged_decode_fn`'s, over the columns within ``chunk_lens``.
     """
     _require_paged_stack(cfg, "paged_chunk_prefill_fn")
     n, C = tokens.shape
@@ -1014,22 +1092,28 @@ def paged_chunk_prefill_fn(cfg: ModelConfig, params, k_pool, v_pool, tables,
     bt = jnp.clip(tables, 0, nb_pool - 1)
     L = tables.shape[1] * block_size
 
+    blocks, unpack = _serve_blocks(cfg, params["blocks"])
+
     def layer(xx, xs):
         p, kp, vp = xs
+        p = unpack(p)
         q, k, v = _chunk_qkv(cfg, p, xx, sin, cos)
         kp = kp.at[wblk, woff].set(k.astype(kp.dtype))
         vp = vp.at[wblk, woff].set(v.astype(vp.dtype))
         kc = _paged_contiguous(kp, bt, n, L)
         vc = _paged_contiguous(vp, bt, n, L)
         o = attn_lib.chunk_attention(q, kc, vc, q_pos=posmat, kv_len=kv_len)
-        xx = _chunk_finish(cfg, p, xx, o, mesh=mesh, batch_axes=batch_axes)
-        return xx, (kp, vp)
+        xx, counters = _chunk_finish(
+            cfg, p, xx, o,
+            valid=lambda: jnp.arange(C)[None, :] < chunk_lens[:, None],
+            mesh=mesh, batch_axes=batch_axes)
+        return xx, (kp, vp, counters)
 
-    x, (kps, vps) = jax.lax.scan(layer, x, (params["blocks"], k_pool,
-                                            v_pool))
+    x, (kps, vps, counters) = jax.lax.scan(
+        layer, x, (blocks, k_pool, v_pool))
     idx = jnp.clip(chunk_lens - 1, 0, C - 1)
     x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
-    return _lm_logits(cfg, params, x_last), kps, vps
+    return _lm_logits(cfg, params, x_last), kps, vps, _layer_sums(counters)
 
 
 def decode_fn(cfg: ModelConfig, params, cache, tokens, *, mesh=None,

@@ -19,7 +19,10 @@ Every span name the program uses is in :data:`SPANS`; nesting per step:
   * ``serve.plan`` — chunk planning with its block demand and
     preemption loop;
   * ``serve.chunk`` — one prefill-chunk program; args ``rows`` (live),
-    ``bucket``, ``tokens``, ``chunk``;
+    ``bucket``, ``tokens``, ``chunk``, and for a model with expert
+    layers ``moe_pairs``, ``moe_hit``, ``moe_max`` (the program's
+    counters, ``repro.models.moe.MOE_COUNTERS``, each summed over
+    layers);
 
     * ``serve.chunk.launch`` — batch build, host-to-device transfers,
       dispatch;
@@ -31,7 +34,7 @@ Every span name the program uses is in :data:`SPANS`; nesting per step:
   * ``serve.account`` — loads, imbalance, the modelled clock and
     energy;
   * ``serve.decode`` — one decode program; args ``rows``, ``bucket``,
-    ``tokens``;
+    ``tokens``, and the expert counters as for ``serve.chunk``;
 
     * ``serve.decode.launch`` / ``serve.decode.wait`` — as for chunks;
 

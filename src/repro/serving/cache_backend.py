@@ -40,6 +40,10 @@ relies on:
 * All methods are synchronous with respect to the host arrays the engine
   reads (``lengths`` bookkeeping must be visible immediately after the
   call returns).
+* ``moe_counts`` holds what the last ``decode`` or ``prefill_chunk``
+  call's expert layers counted (``repro.models.moe.MOE_COUNTERS``, each
+  summed over layers, read back with the tokens), or None where the
+  model has no expert layer or the backend does not count.
 """
 from __future__ import annotations
 
@@ -53,6 +57,7 @@ import numpy as np
 
 from ..configs.base import ModelConfig
 from ..obs.spans import span
+from ..models.moe import MOE_COUNTERS
 from ..models import (
     chunk_prefill_fn,
     decode_fn,
@@ -183,6 +188,7 @@ class CacheBackend(abc.ABC):
     """
 
     name: str = "base"
+    moe_counts: Optional[dict] = None
 
     @abc.abstractmethod
     def write_prefill(self, mini_cache, src: np.ndarray, dst: np.ndarray,
@@ -545,13 +551,23 @@ class PagedCacheBackend(CacheBackend):
 
     def prefill_chunk(self, toks, offs, clens, slots) -> np.ndarray:
         with span("serve.chunk.launch"):
-            logits = self._launch_chunk(toks, offs, clens, slots)
+            logits, counters = self._launch_chunk(toks, offs, clens, slots)
         with span("serve.chunk.wait"):
-            return np.asarray(logits)
+            return self._read_back(logits, counters)
+
+    def _read_back(self, out, counters) -> np.ndarray:
+        """A program's result on the host; its expert counters, where it
+        has them, come back in the same transfer."""
+        if counters is None:
+            return np.asarray(out)
+        out, counters = jax.device_get((out, counters))
+        self.moe_counts = dict(zip(MOE_COUNTERS, counters.tolist()))
+        return np.asarray(out)
 
     def _launch_chunk(self, toks, offs, clens, slots):
         """Grow the rows' block tables, build the write coordinates and
-        dispatch the chunk program; returns its logits unread."""
+        dispatch the chunk program; returns its logits and expert
+        counters unread."""
         bs = self.block_size
         nb, C = toks.shape
         for j in range(nb):
@@ -570,25 +586,26 @@ class PagedCacheBackend(CacheBackend):
                         np.take_along_axis(tables, bidx, axis=1),
                         self.n_blocks).astype(np.int32)
         woff = (posm % bs).astype(np.int32)
-        logits, kp, vp = self._chunk_jit(
+        logits, kp, vp, counters = self._chunk_jit(
             self.params, self.kv.k_pool, self.kv.v_pool,
             jnp.asarray(tables), jnp.asarray(toks, jnp.int32),
             jnp.asarray(offs, jnp.int32), jnp.asarray(clens, jnp.int32),
             jnp.asarray(wblk), jnp.asarray(woff))
         self.kv.k_pool, self.kv.v_pool = kp, vp
-        return logits
+        return logits, counters
 
     def decode(self, slot_tokens, active_idx, bucket) -> np.ndarray:
         n = active_idx.size
         with span("serve.decode.launch"):
-            nxt = self._launch_decode(slot_tokens, active_idx, bucket)
+            nxt, counters = self._launch_decode(slot_tokens, active_idx,
+                                                bucket)
         with span("serve.decode.wait"):
-            return np.asarray(nxt)[:n]
+            return self._read_back(nxt, counters)[:n]
 
     def _launch_decode(self, slot_tokens, active_idx, bucket):
         """Append each row's token slot, build the bucket's tables and
         write coordinates and dispatch the decode program; returns its
-        next tokens unread."""
+        next tokens and expert counters unread."""
         n = active_idx.size
         self.kv.append_tokens(active_idx)
         lens = np.zeros(bucket, np.int32)
@@ -605,12 +622,12 @@ class PagedCacheBackend(CacheBackend):
         off = (pos % self.block_size).astype(np.int32)
         toks = np.zeros(bucket, np.int32)
         toks[:n] = slot_tokens[active_idx]
-        nxt, kp, vp = self._decode_jit(
+        nxt, kp, vp, counters = self._decode_jit(
             self.params, self.kv.k_pool, self.kv.v_pool,
             jnp.asarray(tables), jnp.asarray(lens), jnp.asarray(blk),
             jnp.asarray(off), jnp.asarray(toks))
         self.kv.k_pool, self.kv.v_pool = kp, vp
-        return nxt
+        return nxt, counters
 
     # -- memory pressure (engine-driven preemption) ---------------------
     def decode_block_demand(self, active_idx: np.ndarray) -> int:

@@ -852,7 +852,7 @@ class ServingEngine:
         C = self.scheduler.chunk
         total = sum(n for _, _, n in plan)
         with span("serve.chunk", rows=rows, bucket=nbp, tokens=total,
-                  chunk=C):
+                  chunk=C) as sp:
             toks = np.zeros((nbp, C), dtype=np.int32)
             offs = np.zeros(nbp, dtype=np.int32)
             clens = np.zeros(nbp, dtype=np.int32)
@@ -862,6 +862,7 @@ class ServingEngine:
                 toks[j, :n] = job.tokens[off:off + n]
                 offs[j], clens[j], slots[j] = off, n, slot
             logits = self.backend.prefill_chunk(toks, offs, clens, slots)
+            self._record_moe(sp)
         with span("serve.chunk.commit"):
             self._commit_chunks(plan, logits)
         return total
@@ -1151,6 +1152,14 @@ class ServingEngine:
                     self.slot_load[s] = 0.0
                     self.table.active[s] = False
 
+    def _record_moe(self, sp) -> None:
+        """Put the expert counters of the program that just returned on
+        its span (args ``moe_pairs``, ``moe_hit``, ``moe_max``, each
+        summed over layers); a model without experts records nothing."""
+        counts = self.backend.moe_counts
+        if counts is not None:
+            sp.set_metadata(**counts)
+
     def _decode_step_vec(self, active_idx: np.ndarray) -> None:
         """Vectorized decode path: compact the active slots into the
         smallest decode bucket and let the cache backend run the model
@@ -1158,8 +1167,9 @@ class ServingEngine:
         block-table indirection for the paged backend)."""
         n = active_idx.size
         nb = next(b for b in self._buckets if b >= n)
-        with span("serve.decode", rows=n, bucket=nb, tokens=n):
+        with span("serve.decode", rows=n, bucket=nb, tokens=n) as sp:
             nxt = self.backend.decode(self.slot_tokens, active_idx, nb)
+            self._record_moe(sp)
 
         with span("serve.decode.commit"):
             self.slot_tokens[active_idx] = nxt

@@ -11,12 +11,12 @@ The contract under test (ISSUE 3 / ROADMAP open items):
   prompt work is bounded by the budget and active decoders advance every
   step (never starved), while a large-enough budget degenerates to the
   synchronous schedule.
-* MoE models run end to end on the paged/chunked paths.  Stats parity
-  holds there too (scheduling is token-value independent), but generation
-  parity is NOT asserted for MoE: expert-capacity truncation couples
-  batch rows, so any low-bit numeric difference between attention
-  implementations can legitimately flip routing and diverge token
-  streams — the documented expert-capacity divergence.
+* MoE models run end to end on the paged/chunked paths, and their
+  generations are asserted equal too: the serving expert layer is
+  dropless (``repro.models.moe.moe_ffn_dropless``), so a row's tokens
+  never depend on the other rows of its batch — across decode buckets,
+  between compact and full decode, and between the slot and paged
+  layouts.  Only training keeps a capacity that drops.
 """
 import warnings
 
@@ -162,7 +162,7 @@ class TestSlotPagedParity:
         logits_slot, _ = decode_fn(
             CFG, params, slot_b.cache, jnp.asarray(step_toks), mesh=mesh)
         kv = paged_b.kv
-        nxt_pl, _, _ = paged_decode_fn(
+        nxt_pl, _, _, _ = paged_decode_fn(
             CFG, params, kv.k_pool, kv.v_pool,
             jnp.asarray(kv.block_tables[:3]), jnp.asarray(kv.lengths[:3]),
             jnp.full(3, paged_b.n_blocks, jnp.int32),
@@ -409,13 +409,13 @@ class TestChunkedPrefill:
 
 
 class TestMoEFamily:
-    """MoE engine smoke runs: the paged/chunked paths execute end to end.
+    """MoE engine runs on the paged/chunked paths.
 
-    No generation-parity assert: expert capacity is a *batch-coupled*
-    resource, so compact-decode batch composition and low-bit attention
-    differences can legitimately reroute tokens between experts and
-    diverge the streams.  Stats parity still holds — admission, loads,
-    and completion times never read token values (eos disabled).
+    The serving expert layer is dropless, so generations hold across
+    batch composition: each bucket routes its own rows only, and padding
+    rows and columns route nowhere.  Stats parity holds as for dense
+    models — admission, loads, and completion times never read token
+    values (eos disabled).
     """
 
     def test_moe_paged_chunked_smoke(self, moe_setup):
@@ -444,6 +444,39 @@ class TestMoEFamily:
                      cache_backend="paged")
         for k in STAT_KEYS:
             assert sa[k] == sb[k]
+        for a, b in zip(ra, rb):
+            assert a.generated == b.generated, f"request {a.rid} diverged"
+
+    def test_moe_compact_decode_matches_full_decode(self, moe_setup):
+        """Full decode (``engine_mode="ref"``: every slot, one bucket of
+        G x B rows) against compact decode on the paged pool (the active
+        rows in the smallest bucket, padding rows routed nowhere)."""
+        params, mesh = moe_setup
+        ra = _requests(n=10, seed=5)
+        rb = _requests(n=10, seed=5)
+        _, sa = _run(params, mesh, "fcfs", ra, cfg=MOE_CFG,
+                     engine_mode="ref")
+        _, sb = _run(params, mesh, "fcfs", rb, cfg=MOE_CFG,
+                     cache_backend="paged")
+        for k in STAT_KEYS:
+            assert sa[k] == sb[k]
+        for a, b in zip(ra, rb):
+            assert a.generated == b.generated, f"request {a.rid} diverged"
+
+    @pytest.mark.parametrize("backend", ["slot", "paged"])
+    def test_moe_generations_hold_across_buckets(self, moe_setup, backend):
+        """Each request served alone (one row in the smallest buckets)
+        generates what it generates among nine others (chunked prefill,
+        padding columns present)."""
+        params, mesh = moe_setup
+        together = _requests(n=10, seed=6)
+        _run(params, mesh, "jsq", together, cfg=MOE_CFG,
+             cache_backend=backend, prefill_chunk=16)
+        for want in together[:3]:
+            alone = _requests(n=10, seed=6)[want.rid]
+            _run(params, mesh, "jsq", [alone], cfg=MOE_CFG,
+                 cache_backend=backend, prefill_chunk=16)
+            assert alone.generated == want.generated, want.rid
 
 
 class TestBackendFactory:
